@@ -106,7 +106,7 @@ def apply_linear(params, x: jax.Array, spec: LinearSpec = LinearSpec()) -> jax.A
             else:
                 y = ops.dsp_tuned_matmul_f32(
                     x2, w.values, w.scale, spec=w.spec,
-                    block=w.block or (128, 128, 128),
+                    block=w.block,
                     use_kernel=spec.use_kernel,
                 )
             y = y.reshape(*lead, n_out).astype(x.dtype)

@@ -25,15 +25,16 @@ path):
   mr plans only, the contamination operands) from
   ``kernels.ref.pack_weight_words``, built ONCE at engine build so no decode
   step ever repacks; ``zp_row``: the precomputed zero-point correction
-  ``zp·Σ_k w``; ``w_f32``: the signed grid cast to f32 — on backends whose
-  integer dots lower to scalar loops (CPU), *provably exact* plans run the
-  identical integer matmul through the fast f32 GEMM unit, bit-for-bit
-  (``ref.exact_int_matmul_fits_f32``).
+  ``zp·Σ_k w``; ``w_f32``: the signed grid cast to f32, built only off-TPU
+  — on backends whose integer dots lower to scalar loops (CPU),
+  *provably exact* plans run the identical integer matmul through the
+  fast f32 GEMM unit, bit-for-bit (``ref.exact_int_matmul_fits_f32``); on a
+  TPU the Pallas kernel serves the words.
 
 The storage-vs-HBM tradeoff is explicit: ``payload`` is what a checkpoint /
 HBM-resident copy costs (0.5–1 byte per value), the prepacked operands are
-a decode-speed cache costing extra device bytes (4 bytes per value for
-``words``, +4 for ``w_f32``, +8 for mr ``wsc``).  ``prepack=False`` keeps
+a decode-speed cache costing extra device bytes (2 bytes per value for
+``words``, +4 for mr ``wsc``, +4 for ``w_f32``).  ``prepack=False`` keeps
 storage only.
 
 Norms, biases, embeddings and 1-D leaves stay bf16 (gather tables and
@@ -42,12 +43,14 @@ vector ops gain nothing from nibble packing).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Iterator
 
 import jax
 import jax.numpy as jnp
 
 from ..kernels import ref
+from ..kernels.ops import on_tpu
 from ..kernels.ref import INT4_EXACT, PackedDotSpec
 from .quantize import quantize_signed, zero_point_correction
 
@@ -178,32 +181,19 @@ class DspTunedLeaf:
         return self.payload
 
     def _prepack(self, values) -> None:
-        """Build the compute operands once (engine build time)."""
-        v32 = values.astype(jnp.int32)
-        zp = 1 << (self.spec.bits_a - 1)
-
-        def one(m):
-            packed = ref.pack_weight_words(m, self.spec)
-            return packed.words, packed.wsc, zero_point_correction(m, zp)
-
-        if v32.ndim == 2:
-            self.words, self.wsc, self.zp_row = one(v32)
-        else:
-            lead = v32.shape[:-2]
-            flat = v32.reshape((-1,) + v32.shape[-2:])
-            if self.spec.uses_mr:
-                words, wsc, zp_row = jax.vmap(one)(flat)
-                self.wsc = wsc.reshape(lead + wsc.shape[1:])
-            else:
-                words, _, zp_row = jax.vmap(lambda m: one(m))(flat)
-            self.words = words.reshape(lead + words.shape[1:])
-            self.zp_row = zp_row.reshape(lead + zp_row.shape[1:])
+        """Build the compute operands once (engine build time), one layer
+        of a stacked weight at a time."""
+        spec = self.spec
+        self.words, self.wsc, self.zp_row = _prepack_layers(values, spec)
         # the f32 shortcut is only bit-safe when the plan is proven exact
-        # AND every partial sum fits the f32 mantissa
-        k = v32.shape[-2]
-        max_a = (1 << self.spec.bits_a) - 1
-        max_w = 1 << (self.spec.bits_w - 1)
-        if self.exact and ref.exact_int_matmul_fits_f32(k, max_a, max_w):
+        # AND every partial sum fits the f32 mantissa, and only pays off
+        # where integer dots lower to scalar loops: never on a TPU, where
+        # the Pallas kernel serves the words
+        k = values.shape[-2]
+        max_a = (1 << spec.bits_a) - 1
+        max_w = 1 << (spec.bits_w - 1)
+        if (self.exact and not on_tpu()
+                and ref.exact_int_matmul_fits_f32(k, max_a, max_w)):
             self.w_f32 = values.astype(jnp.float32)
 
     @property
@@ -364,26 +354,72 @@ def fuse_projection_weights(params, fuse_attn: bool = True,
     return walk(params)
 
 
+def map_layers(fn, w: jax.Array):
+    """Apply ``fn`` to each (d_in, d_out) matrix of a (…, d_in, d_out)
+    stack, one matrix at a time (``lax.map``), so a conversion's
+    temporaries — an f32 copy, int32 words — exist for one layer, never
+    for the whole stack.  Outputs keep the stack's leading axes; ``None``
+    outputs pass through."""
+    lead = w.shape[:-2]
+    if not lead:
+        return fn(w)
+    out = jax.lax.map(fn, w.reshape((-1,) + w.shape[-2:]))
+    return jax.tree.map(lambda a: a.reshape(lead + a.shape[1:]), out)
+
+
+# The engine-build conversions, jitted per (shape, plan) so repeated builds
+# reuse one compiled program per weight shape.
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _quantize_layers(w: jax.Array, bits: int):
+    """Per-layer signed quantization → (int8 values, f32 scale)."""
+
+    def one(m):
+        q = quantize_signed(m.astype(jnp.float32), bits=bits, axis=0)
+        return q.values, q.scale
+
+    return map_layers(one, w)
+
+
+@jax.jit
+def _pack_int4_layers(w: jax.Array):
+    """Per-layer int4 quantization → (uint8 nibbles, f32 scale)."""
+
+    def one(m):
+        q = quantize_signed(m.astype(jnp.float32), bits=4, axis=0)
+        return ref.pack_int4_weights(q.values), q.scale
+
+    return map_layers(one, w)
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _prepack_layers(values: jax.Array, spec: PackedDotSpec):
+    """Per-layer compute operands of a tuned plan → (words, wsc, zp_row)."""
+    zp = 1 << (spec.bits_a - 1)
+
+    def one(m):
+        m = m.astype(jnp.int32)
+        packed = ref.pack_weight_words(m, spec)
+        return packed.words, packed.wsc, zero_point_correction(m, zp)
+
+    return map_layers(one, values)
+
+
 def _pack_matrix(w: jax.Array, prepack: bool = False) -> dict:
     """(…, d_in, d_out) float -> packed int4 nibbles + per-channel scale.
 
     ``prepack=True`` (engine build) additionally stores ``w_f32`` — the
     int4 grid decoded once and cast to f32 — so the decode fast path runs
     the exact int8×int4 matmul through the f32 GEMM unit instead of
-    unpacking nibbles and looping an integer dot every step."""
-    lead = w.shape[:-2]
-    d_in, d_out = w.shape[-2:]
-    w2 = w.reshape((-1, d_in, d_out)).astype(jnp.float32)
-    q = jax.vmap(lambda m: quantize_signed(m, bits=4, axis=0))(w2)
-    packed = jax.vmap(ref.pack_int4_weights)(q.values)
-    leaf = {
-        "packed": packed.reshape(lead + (d_in // 2, d_out)),
-        "scale": q.scale.reshape(lead + (1, d_out)).astype(jnp.float32),
-    }
-    if prepack and ref.exact_int_matmul_fits_f32(d_in, 128, 8):
-        leaf["w_f32"] = (
-            q.values.astype(jnp.float32).reshape(lead + (d_in, d_out))
-        )
+    unpacking nibbles and looping an integer dot every step.  Not on a
+    TPU: there the Pallas kernel serves the nibbles directly."""
+    d_in = w.shape[-2]
+    packed, scale = _pack_int4_layers(w)
+    leaf = {"packed": packed, "scale": scale}
+    if (prepack and not on_tpu()
+            and ref.exact_int_matmul_fits_f32(d_in, 128, 8)):
+        leaf["w_f32"] = unpack_signed_nibbles(packed).astype(jnp.float32)
     return leaf
 
 
@@ -394,13 +430,10 @@ def _tune_matrix(w: jax.Array, spec: PackedDotSpec,
                  prepack: bool = True) -> DspTunedLeaf:
     """(…, d_in, d_out) float -> plan-grid signed ints + per-channel scale
     (+ the prepacked compute operands when ``prepack``)."""
-    lead = w.shape[:-2]
-    d_in, d_out = w.shape[-2:]
-    w2 = w.reshape((-1, d_in, d_out)).astype(jnp.float32)
-    q = jax.vmap(lambda m: quantize_signed(m, bits=spec.bits_w, axis=0))(w2)
+    values, scale = _quantize_layers(w, spec.bits_w)
     return DspTunedLeaf(
-        values=q.values.astype(jnp.int8).reshape(lead + (d_in, d_out)),
-        scale=q.scale.reshape(lead + (1, d_out)).astype(jnp.float32),
+        values=values,
+        scale=scale,
         spec=spec,
         block=block,
         decode_block=decode_block,

@@ -22,6 +22,7 @@ __all__ = [
     "QuantizedTensor",
     "quantize_signed",
     "quantize_unsigned",
+    "unsigned_scale",
     "dequantize",
     "fake_quant_signed",
     "fake_quant_unsigned",
@@ -62,6 +63,11 @@ def quantize_signed(x: jax.Array, bits: int = 4, axis=-1) -> QuantizedTensor:
     return QuantizedTensor(q, scale, bits=bits, zero_point=0)
 
 
+def unsigned_scale(x: jax.Array, bits: int = 4, axis=-1) -> jax.Array:
+    """The scale :func:`quantize_unsigned` quantizes ``x`` with."""
+    return _absmax_scale(x, axis, (1 << (bits - 1)) - 1)
+
+
 def quantize_unsigned(x: jax.Array, bits: int = 4, axis=-1) -> QuantizedTensor:
     """Offset-binary quantization: values in ``[0, 2^b - 1]``, zp at mid.
 
@@ -70,8 +76,7 @@ def quantize_unsigned(x: jax.Array, bits: int = 4, axis=-1) -> QuantizedTensor:
     value above the zero point collapsed — a silent a8 activation bug the
     prepacked kernel's fused-quantize parity check caught)."""
     zp = 1 << (bits - 1)
-    qmax = zp - 1
-    scale = _absmax_scale(x, axis, qmax)
+    scale = unsigned_scale(x, bits, axis)
     q = jnp.clip(jnp.round(x / scale) + zp, 0, (1 << bits) - 1).astype(jnp.uint8)
     return QuantizedTensor(q, scale, bits=bits, zero_point=zp)
 

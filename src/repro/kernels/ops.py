@@ -21,13 +21,20 @@ import jax.numpy as jnp
 from ..core.quantize import (
     quantize_signed,
     quantize_unsigned,
+    unsigned_scale,
     zero_point_correction,
 )
 from . import ref
-from .int4_matmul import int4_matmul
-from .packed_matmul import default_block_for, packed_matmul, packed_matmul_prepacked
+from .int4_matmul import DEFAULT_BLOCK as INT4_BLOCK, int4_matmul
+from .packed_matmul import (
+    DECODE_BLOCK,
+    default_block_for,
+    packed_matmul,
+    packed_matmul_prepacked,
+)
 
 __all__ = [
+    "on_tpu",
     "auto_interpret",
     "packed_matmul_f32",
     "dsp_tuned_matmul_f32",
@@ -40,19 +47,18 @@ __all__ = [
 from .ref import INT4_EXACT, PackedDotSpec
 
 
+def on_tpu() -> bool:
+    """Whether the default backend is a TPU: there the Pallas kernels are
+    compiled and serving runs them; elsewhere they run in interpret mode
+    and serving uses the jnp references (plus the f32 grids of
+    ``core.packed_params``, which pay off only where integer dots lower to
+    scalar loops)."""
+    return jax.default_backend() == "tpu"
+
+
 def auto_interpret() -> bool:
     """Pallas interpret mode everywhere but a real TPU backend."""
-    return jax.default_backend() != "tpu"
-
-
-def _pad_to(x: jax.Array, mult: int, axis: int, value=0) -> jax.Array:
-    size = x.shape[axis]
-    rem = (-size) % mult
-    if rem == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, rem)
-    return jnp.pad(x, pad, constant_values=value)
+    return not on_tpu()
 
 
 @functools.partial(
@@ -62,7 +68,7 @@ def packed_matmul_f32(
     x: jax.Array,
     w: jax.Array,
     spec: PackedDotSpec = INT4_EXACT,
-    block=(128, 128, 128),
+    block=None,
     interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> jax.Array:
@@ -77,7 +83,8 @@ def packed_matmul_f32(
     # ragged shapes are padded (bit-transparently) inside the compute paths
     if use_kernel:
         acc = packed_matmul(
-            xq.values, wq.values, spec=spec, block=block,
+            xq.values, wq.values, spec=spec,
+            block=block or default_block_for(x.shape[0], spec),
             interpret=auto_interpret() if interpret is None else interpret,
         )
     else:
@@ -94,7 +101,7 @@ def dsp_tuned_matmul_f32(
     w_values: jax.Array,
     w_scale: jax.Array,
     spec: PackedDotSpec,
-    block=(128, 128, 128),
+    block=None,
     interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> jax.Array:
@@ -109,7 +116,8 @@ def dsp_tuned_matmul_f32(
     wv = w_values.astype(jnp.int32)
     if use_kernel:
         acc = packed_matmul(
-            xq.values, wv, spec=spec, block=block,
+            xq.values, wv, spec=spec,
+            block=block or default_block_for(x.shape[0], spec),
             interpret=auto_interpret() if interpret is None else interpret,
         )
     else:
@@ -149,7 +157,8 @@ def dsp_tuned_matmul_prepacked_f32(
 
     With the kernel path, the activation quantize is fused into the kernel
     prologue (``x_scale``/``x_zp``): the int activation tensor never stages
-    through HBM.
+    through HBM, and the result is bit-identical to the reference path's
+    staged quantize.
     """
     m = x.shape[0]
     if exact_f32 and w_f32 is not None:
@@ -158,8 +167,7 @@ def dsp_tuned_matmul_prepacked_f32(
         # bounded by zp-1 by construction); the f32 GEMM then computes the
         # exact packed-plan matmul — see the docstring
         zp = 1 << (spec.bits_a - 1)
-        amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-        x_scale = jnp.maximum(amax, 1e-8) / (zp - 1)
+        x_scale = unsigned_scale(x, bits=spec.bits_a, axis=-1)
         q = jnp.round(x / x_scale) + zp
         acc = q @ w_f32  # exact: fits the f32 mantissa
         acc = acc - zp_row.astype(jnp.float32)[None, :]
@@ -167,24 +175,22 @@ def dsp_tuned_matmul_prepacked_f32(
     if use_kernel:
         # fused-quantize prologue: pass raw f32 + per-row scale to the kernel
         zp = 1 << (spec.bits_a - 1)
-        amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-        x_scale = jnp.maximum(amax, 1e-8) / (zp - 1)
+        x_scale = unsigned_scale(x, bits=spec.bits_a, axis=-1)
         acc = packed_matmul_prepacked(
             x.astype(jnp.float32), words, wsc, spec=spec,
             block=block or default_block_for(m, spec),
             interpret=auto_interpret() if interpret is None else interpret,
             x_scale=x_scale, x_zp=zp,
         )
-        out_scale = x_scale
     else:
         xq = quantize_unsigned(x, bits=spec.bits_a, axis=-1)
+        x_scale = xq.scale
         acc = ref.ref_packed_matmul_prepacked(
             xq.values.astype(jnp.int32), ref.PackedWeightWords(words, wsc),
             spec,
         )
-        out_scale = xq.scale
     acc = acc - zp_row[None, :]
-    return acc.astype(jnp.float32) * out_scale * w_scale
+    return acc.astype(jnp.float32) * x_scale * w_scale
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "use_kernel"))
@@ -199,22 +205,19 @@ def int4_matmul_f32(
     """float (M, K) × packed int4 (K//2, N) → f32, int8 activations.
 
     The ref path runs unpadded (an (M, K, N) problem needs no MXU tile
-    grid); the kernel path pads to a decode-aware block — small-M GEMV
-    blocks for decode shapes instead of 128-row tiles that used to pad a
-    2-slot decode ~64x in M.
+    grid); the kernel pads to a decode-aware block — small-M GEMV blocks
+    for decode shapes instead of 128-row tiles that would pad a 2-slot
+    decode ~64x in M.
     """
-    m, k = x.shape
     xq = quantize_signed(x, bits=8, axis=-1)
     if use_kernel:
         if block is None:
-            block = default_block_for(m)
-        bm, bn, bk = block
-        xv = _pad_to(_pad_to(xq.values, bm, 0), bk, 1)
-        wv = _pad_to(_pad_to(w_packed, bk // 2, 0), bn, 1)
+            block = (DECODE_BLOCK if x.shape[0] <= DECODE_BLOCK[0]
+                     else INT4_BLOCK)
         acc = int4_matmul(
-            xv, wv, block=block,
+            xq.values, w_packed, block=block,
             interpret=auto_interpret() if interpret is None else interpret,
-        )[:m, : w_packed.shape[1]]
+        )
     else:
         acc = ref.ref_int4_matmul(xq.values, w_packed)
     return acc.astype(jnp.float32) * xq.scale * w_scale

@@ -496,9 +496,10 @@ def packed_tile_matmul_prepacked(
 ) -> jax.Array:
     """The COMPUTE stage: already-packed weight words × unsigned activations.
 
-    Shared verbatim by the jnp reference and BOTH Pallas kernel bodies (the
-    repacking and the prepacked entry), so all of them are bit-identical by
-    construction.  ``x_u``: (m, k) with ``k = n_chunks * spec.chunk``;
+    The jnp reference of the Pallas kernel (``kernels.packed_matmul``),
+    which computes the same wrapping int32 chunk sums on the VPU and shares
+    :func:`extract_accumulated_field`, so the two are bit-identical.
+    ``x_u``: (m, k) with ``k = n_chunks * spec.chunk``;
     ``words``/``wsc`` as produced by :func:`pack_weight_words`.
 
     Per column: pack the activation slice's pair words, contract ALL
@@ -546,8 +547,8 @@ def packed_tile_matmul(x_u: jax.Array, w_s: jax.Array,
     """Pack + compute in one call (the per-call path): (m, k) unsigned ×
     (k, n) signed → (m, n) int32, ``k`` a multiple of ``spec.chunk``.
 
-    Kept as the kernel-body entry for callers whose weights change every
-    call (training-style use); serving packs once via
+    Kept for callers whose weights change every call (training-style
+    use); serving packs once via
     :func:`pack_weight_words` and runs only the compute stage per step.
     """
     packed = pack_weight_words(w_s, spec)
@@ -568,7 +569,7 @@ def ref_packed_matmul(
     each extracted dot field shifted by its slice offset — all in wrapping
     int32 arithmetic, so kernel/ref/simulator stay bit-identical even where
     a (caller-side) output overflow wraps.  The compute itself lives in
-    :func:`packed_tile_matmul`, shared with the kernel body.
+    :func:`packed_tile_matmul`.
     """
     x_u, w_s = _pad_k(x_u, w_s, spec.chunk)
     return packed_tile_matmul(x_u, w_s, spec)
@@ -644,11 +645,11 @@ def unpack_int4_weights(packed: jax.Array) -> jax.Array:
 
 
 def ref_int4_matmul(x_q: jax.Array, w_packed: jax.Array) -> jax.Array:
-    """Oracle for the production kernel: unpack then exact int32 matmul."""
-    w = unpack_int4_weights(w_packed)
+    """Oracle for the production kernel: unpack then exact int8 matmul
+    with int32 accumulation (``x_q``: int8, as ``quantize_signed`` makes)."""
     return jax.lax.dot_general(
-        x_q.astype(jnp.int32),
-        w.astype(jnp.int32),
+        x_q,
+        unpack_int4_weights(w_packed),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )

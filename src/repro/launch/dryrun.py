@@ -17,9 +17,12 @@ Usage:
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512"
+)))
 
 # ^ MUST precede every other import (jax locks device count on first init).
+# Appends to the caller's flags; nothing on the serving path imports this.
 
 import argparse
 import dataclasses

@@ -13,10 +13,12 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..models import transformer as T
 from ..models.registry import get_config
+from ..runtime.compile_cache import enable_compile_cache
 from ..serving import (
     ContinuousEngine,
     Engine,
@@ -26,7 +28,17 @@ from ..serving import (
 )
 
 
+def init_serving_params(cfg, seed: int = 0):
+    """Random weights for ``cfg`` in its compute dtype (``cfg.dtype``),
+    made on the default device under jit — a full-width model never passes
+    through a host or float32 copy."""
+    return jax.jit(lambda key: T.init_params(key, cfg, jnp.dtype(cfg.dtype)))(
+        jax.random.PRNGKey(seed)
+    )
+
+
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -54,6 +66,12 @@ def main() -> None:
     ap.add_argument("--quant", default="native",
                     choices=["native", "int8", "int4_packed", "dsp_packed",
                              "dsp_tuned", "dsp_mixed"])
+    ap.add_argument("--use-kernel", action=argparse.BooleanOptionalAction,
+                    default=None,
+                    help="packed modes: run the compiled Pallas kernels "
+                         "(--use-kernel) or the jnp references "
+                         "(--no-use-kernel); default: kernels on a TPU at "
+                         "--tp 1, references elsewhere")
     ap.add_argument("--error-budget", type=float, default=0.5,
                     help="dsp_tuned: max MAE per extraction a plan may incur")
     def _plan_bits(arg: str) -> tuple[int, int] | str:
@@ -117,14 +135,14 @@ def main() -> None:
                          "'deadline') instead of occupying lanes")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree: shard packed weights over "
-                         "the first --tp devices (runtime.tp_packed; decode "
+                         "--tp devices (runtime.tp_packed; decode "
                          "stays bit-identical to --tp 1). CPU smoke: set "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N")
     ap.add_argument("--replicas", type=int, default=1,
                     help="data-parallel engine replicas behind a "
                          "join-shortest-queue front (serving.replica); each "
-                         "replica is a full engine — combine with --tp for "
-                         "2D scaling")
+                         "replica is a full engine on its own --tp devices "
+                         "— combine with --tp for 2D scaling")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--top-p", type=float, default=1.0)
@@ -132,12 +150,13 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_serving_params(cfg)
     engine_cls = ContinuousEngine if args.engine == "continuous" else Engine
     serve_cfg = ServeConfig(
         n_slots=args.slots, max_len=args.max_len,
         prefill_chunk=args.prefill_chunk, quant_mode=args.quant,
-        seed=args.seed, error_budget=args.error_budget,
+        use_kernel=args.use_kernel, seed=args.seed,
+        error_budget=args.error_budget,
         autotune_plans=args.autotune_plans,
         plan_bits="auto" if args.quant == "dsp_mixed" else args.plan_bits,
         mixed_budget=args.mixed_budget,

@@ -27,6 +27,7 @@ from ..models import transformer as T
 from ..models.registry import get_config
 from ..optim.adamw import AdamWConfig, adamw_init
 from ..optim.schedule import cosine_with_warmup
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.fault_tolerance import Heartbeat
 from ..runtime.sharding import param_shardings
 from .mesh import make_local_mesh, make_production_mesh
@@ -47,6 +48,7 @@ def build_state(cfg, mesh, opt_cfg, seed=0):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

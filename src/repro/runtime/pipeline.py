@@ -24,11 +24,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models import transformer as T
 from ..models.config import ModelConfig
-from .jax_compat import shard_map
 
 __all__ = ["split_stages", "pipeline_forward"]
 

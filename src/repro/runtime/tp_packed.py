@@ -28,6 +28,9 @@ machine-checked certificate of the cross-device accumulation budget.
 
 Bit-identity per path:
 
+* **row, int4 nibbles**: the activation row is quantized outside
+  ``shard_map``; each shard runs the exact int8 × int4 integer matmul on
+  its own packed rows and an int32 ``psum`` reduces — order-independent.
 * **row, proven-exact plans** (the CPU serving default): the activation
   row is quantized OUTSIDE ``shard_map`` (the per-row scale must see
   every channel), the f32 GEMM runs per K-shard and a f32 ``psum``
@@ -60,10 +63,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels import ref
-from .jax_compat import shard_map
 from .sharding import linear_partition
 
 __all__ = ["TpLinear", "shard_params_tp", "apply_tp_linear"]
@@ -74,7 +77,7 @@ class TpLinear:
     """A mesh-partitioned serving linear.
 
     Wraps one quantized weight leaf (a ``DspTunedLeaf`` or an int4
-    ``{"packed","scale","w_f32"}`` dict) whose arrays were ``device_put``
+    ``{"packed","scale"}`` dict) whose arrays were ``device_put``
     onto the mesh by :func:`shard_params_tp`.  The wrapper is a pytree
     node — the inner leaf's arrays are children (so ``lax.scan`` over
     stacked scan groups slices through it and checkpoint/eval_shape
@@ -218,30 +221,28 @@ def _wrap_tuned(leaf, path: str, mesh, S: int, axis: str):
 
 
 def _wrap_int4(leaf: dict, path: str, mesh, S: int, axis: str):
+    """Partition an int4 leaf's nibbles: by output column (col) or by
+    packed row pairs of the contraction axis (row).  Shards compute exact
+    int32 partial sums off their own nibbles (no f32 grid is sharded)."""
     kind = linear_partition(path)
-    w_f32 = leaf.get("w_f32")
-    if kind is None or w_f32 is None:
-        # the nibble-unpacking fallback quantizes per call — replicate
+    packed, scale = leaf["packed"], leaf["scale"]
+    if kind is None:
         return leaf
     if kind == "col":
-        if leaf["scale"].shape[-1] % S:
-            return leaf
+        if scale.shape[-1] % S:
+            return leaf  # replicate fallback, mirroring param_pspec
         new = {
-            "packed": _put(mesh, leaf["packed"],
-                           _last_axis_pspec(leaf["packed"], axis)),
-            "scale": _put(mesh, leaf["scale"],
-                          _last_axis_pspec(leaf["scale"], axis)),
-            "w_f32": _put(mesh, w_f32, _last_axis_pspec(w_f32, axis)),
+            "packed": _put(mesh, packed, _last_axis_pspec(packed, axis)),
+            "scale": _put(mesh, scale, _last_axis_pspec(scale, axis)),
         }
         return TpLinear(new, kind="col", mesh=mesh, n_shards=S, axis=axis)
-    if w_f32.shape[-2] % S:
+    if packed.shape[-2] % S:
         return leaf
     new = {
-        "packed": leaf["packed"],
-        "scale": leaf["scale"],
-        "w_f32": _put(
-            mesh, w_f32, P(*([None] * (w_f32.ndim - 2) + [axis, None]))
+        "packed": _put(
+            mesh, packed, P(*([None] * (packed.ndim - 2) + [axis, None]))
         ),
+        "scale": scale,
     }
     return TpLinear(new, kind="row", mesh=mesh, n_shards=S, axis=axis)
 
@@ -414,32 +415,36 @@ def _int4_col(w: TpLinear, x):
 
     d = w.inner
 
-    def body(xl, w_f32, scale):
-        local = ops.int4_prepacked_matmul_f32(xl, w_f32, scale)
+    def body(xl, packed, scale):
+        local = ops.int4_matmul_f32(xl, packed, scale, use_kernel=False)
         return jax.lax.all_gather(local, w.axis, axis=1, tiled=True)
 
     return shard_map(
         body, mesh=w.mesh,
         in_specs=(P(None, None), P(None, w.axis), P(None, w.axis)),
         out_specs=P(None, None), check_vma=False,
-    )(x, d["w_f32"], d["scale"])
+    )(x, d["packed"], d["scale"])
 
 
 def _int4_row(w: TpLinear, x):
-    from ..kernels.ops import _quantize_signed_f32
+    from ..core.quantize import quantize_signed
 
     d = w.inner
-    q, x_scale = _quantize_signed_f32(x, bits=8)
+    # quantize the FULL activation row outside the shard_map (the per-row
+    # scale sees every channel, exactly as on one device); each shard's
+    # exact int32 partial sum is psummed — integer sums are
+    # order-independent, so the result is the one-device integer matmul
+    xq = quantize_signed(x, bits=8, axis=-1)
 
     def gemm(ql, wl):
-        return jax.lax.psum(ql @ wl, w.axis)
+        return jax.lax.psum(ref.ref_int4_matmul(ql, wl), w.axis)
 
     acc = shard_map(
         gemm, mesh=w.mesh,
         in_specs=(P(None, w.axis), P(w.axis, None)),
         out_specs=P(None, None), check_vma=False,
-    )(q, d["w_f32"])
-    return acc * x_scale * d["scale"]
+    )(xq.values, d["packed"])
+    return acc.astype(jnp.float32) * xq.scale * d["scale"]
 
 
 def apply_tp_linear(w: TpLinear, x, quant_spec):

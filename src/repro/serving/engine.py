@@ -66,6 +66,7 @@ from ..core.packed_params import (
     fuse_projection_weights,
     quantize_for_serving,
 )
+from ..kernels.ops import on_tpu
 from ..models import transformer as T
 from ..models.config import ModelConfig
 from .paged_cache import OutOfPages, PageAllocator
@@ -86,9 +87,10 @@ class ServeConfig:
     placement (``tp`` — tensor parallelism over the serving mesh,
     DESIGN.md §4) and policy (deadlines, the governor, default sampling).
     ``__post_init__`` rejects contradictory combinations at construction
-    so an engine never has to re-validate; the one mutation it performs
-    is promoting ``plan_bits="auto"`` + dsp_tuned to ``dsp_mixed``
-    (per-layer width allocation IS the mixed mode).
+    so an engine never has to re-validate; the two mutations it performs
+    are promoting ``plan_bits="auto"`` + dsp_tuned to ``dsp_mixed``
+    (per-layer width allocation IS the mixed mode) and resolving
+    ``use_kernel=None`` from the backend.
     """
 
     n_slots: int = 8
@@ -99,7 +101,10 @@ class ServeConfig:
     # weight path: native | int8 | int4_packed | dsp_packed | dsp_tuned |
     # dsp_mixed (see core.packed_params.quantize_for_serving)
     quant_mode: str = "native"
-    use_kernel: bool = False   # Pallas kernels vs jnp refs (CPU tests use ref)
+    # compiled Pallas kernels vs the jnp references for the packed modes;
+    # None resolves from the backend: kernels on a TPU at tp == 1 (no f32
+    # shortcut grids are built there), references elsewhere
+    use_kernel: bool | None = None
     # engine-build weight preprocessing for the packed decode fast path:
     # prepack builds device-resident packed operands once (words / zp rows /
     # f32-exact grids); fuse_projections concatenates same-input projections
@@ -154,8 +159,8 @@ class ServeConfig:
     # degraded weight tiers and swap under load.  False = off; True =
     # default GovernorConfig; or a GovernorConfig instance.
     governor: Any = False
-    # tensor-parallel degree: shard packed weights over the first ``tp``
-    # devices' "model" mesh axis (launch.mesh.make_serving_mesh) and run
+    # tensor-parallel degree: shard packed weights over ``tp`` devices'
+    # "model" mesh axis (launch.mesh.make_serving_mesh) and run
     # the shard_map'd packed arithmetic (runtime.tp_packed) — decode is
     # bit-identical to tp=1 by construction.  Plan searches and the plan-
     # DB key are tp-aware: row-partitioned layers plan against the
@@ -217,6 +222,8 @@ class ServeConfig:
             )
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {self.tp}")
+        if self.use_kernel is None:
+            object.__setattr__(self, "use_kernel", self.tp == 1 and on_tpu())
         if self.tp > 1 and self.use_kernel:
             raise ValueError(
                 "tp > 1 runs the shard_map'd jnp reference paths; "
@@ -391,8 +398,9 @@ def _prepare_serving_params(cfg: ModelConfig, params, serve_cfg: ServeConfig,
     return cfg, params, plan_table, resolved_mixed, float_params, db_stats
 
 
-def _shard_for_tp(params, serve_cfg: ServeConfig):
-    """Mesh-partition a quantized serving tree when ``serve_cfg.tp > 1``.
+def _shard_for_tp(params, serve_cfg: ServeConfig, devices=None):
+    """Mesh-partition a quantized serving tree when ``serve_cfg.tp > 1``,
+    over ``devices`` (default: the first ``tp``).
 
     Returns ``(mesh, params)`` — ``(None, params)`` untouched at tp=1.
     The wrap happens AFTER quantization (the packed operands are what
@@ -404,7 +412,7 @@ def _shard_for_tp(params, serve_cfg: ServeConfig):
     from ..launch.mesh import make_serving_mesh
     from ..runtime.tp_packed import shard_params_tp
 
-    mesh = make_serving_mesh(serve_cfg.tp)
+    mesh = make_serving_mesh(serve_cfg.tp, devices)
     return mesh, shard_params_tp(
         params, mesh, use_kernel=serve_cfg.use_kernel
     )
@@ -486,6 +494,40 @@ def _setup_governor(engine, cfg, float_params, serve_cfg) -> None:
     engine.governor = Governor(gcfg, len(engine.tiers))
 
 
+def _place(engine, devices) -> None:
+    """Commit an engine's weights, precision tiers and cache to its devices;
+    the jitted steps then run there.  Under tensor parallelism the leaves
+    the mesh did not partition are replicated over it (instead of being
+    re-sent from one device every step); at tp=1 everything goes to
+    ``devices[0]`` (a replica's own chip), or stays uncommitted on the
+    default device without ``devices``."""
+    if engine.mesh is None:
+        if not devices:
+            return
+        put = partial(jax.device_put, device=devices[0])
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        rep = NamedSharding(engine.mesh, PartitionSpec())
+
+        def on_mesh(a):
+            return (isinstance(a.sharding, NamedSharding)
+                    and a.sharding.mesh == engine.mesh)
+
+        def put(tree):
+            return jax.tree.map(
+                lambda a: a if on_mesh(a) else jax.device_put(a, rep), tree
+            )
+    engine.cache = put(engine.cache)
+    if engine.tiers is not None:
+        engine.tiers = tuple(
+            dataclasses.replace(t, params=put(t.params)) for t in engine.tiers
+        )
+        engine.params = engine.tiers[engine.active_tier].params
+    else:
+        engine.params = put(engine.params)
+
+
 class Engine:
     """Fixed-slot batched serving engine (DESIGN.md §3).
 
@@ -514,17 +556,20 @@ class Engine:
     """
 
     def __init__(self, cfg: ModelConfig, params, serve_cfg: ServeConfig,
-                 mixed_allocation=None):
+                 mixed_allocation=None, devices=None):
         """``mixed_allocation`` (a ``tuning.MixedAllocation``) skips the
         dsp_mixed engine-build sensitivity pass and serves the given
         per-layer plan table instead — for callers that already measured
         (the serving benchmark probes budgets before building).  Its paths
-        must match this engine's param tree (same fusion settings)."""
+        must match this engine's param tree (same fusion settings).
+        ``devices`` (``serve_cfg.tp`` of them) pins the engine to a device
+        slice — ``serving.replica`` gives each replica its own; default:
+        the first ``tp`` devices, uncommitted at tp=1."""
         (cfg, params, self.plan_table, self.mixed_allocation, float_params,
          self.plan_db_stats) = _prepare_serving_params(
             cfg, params, serve_cfg, mixed_allocation
         )
-        self.mesh, params = _shard_for_tp(params, serve_cfg)
+        self.mesh, params = _shard_for_tp(params, serve_cfg, devices)
         self.cfg = cfg
         self.params = params
         self.scfg = serve_cfg
@@ -558,6 +603,7 @@ class Engine:
             ),
             s_b, s_b1,
         )
+        _place(self, devices)
         self.positions = np.zeros(b, np.int32)
         self.active = np.zeros(b, bool)
         self.last_token = np.zeros(b, np.int32)
@@ -948,11 +994,18 @@ class Engine:
     def peek_logits(self) -> np.ndarray:
         """(n_slots, V) next-token logits for the current state, without
         advancing it — used by the packed-vs-float tolerance tests."""
+        return np.asarray(self._peek(
+            self.params, self.cache, jnp.asarray(self.last_token),
+            jnp.asarray(self.positions),
+        ))
+
+    @partial(jax.jit, static_argnums=(0,))
+    def _peek(self, params, cache, tokens, positions):
         logits, _, _ = T.forward(
-            self.params, self.cfg, jnp.asarray(self.last_token)[:, None],
-            positions=jnp.asarray(self.positions)[:, None], cache=self.cache,
+            params, self.cfg, tokens[:, None], positions=positions[:, None],
+            cache=cache,
         )
-        return np.asarray(logits[:, -1].astype(jnp.float32))
+        return logits[:, -1].astype(jnp.float32)
 
     def stats(self) -> dict:
         """Scheduler counters (queue depth, per-phase tok/s, TTFT/latency
@@ -1012,12 +1065,13 @@ class ContinuousEngine:
     """
 
     def __init__(self, cfg: ModelConfig, params, serve_cfg: ServeConfig,
-                 mixed_allocation=None):
+                 mixed_allocation=None, devices=None):
+        """Arguments as for :class:`Engine`."""
         (cfg, params, self.plan_table, self.mixed_allocation, float_params,
          self.plan_db_stats) = _prepare_serving_params(
             cfg, params, serve_cfg, mixed_allocation
         )
-        self.mesh, params = _shard_for_tp(params, serve_cfg)
+        self.mesh, params = _shard_for_tp(params, serve_cfg, devices)
         self.cfg = cfg
         self.params = params
         self.scfg = serve_cfg
@@ -1062,6 +1116,7 @@ class ContinuousEngine:
               if serve_cfg.watermark_pages is not None else b)
         self.alloc = PageAllocator(n_pages, ps, min(wm, n_pages - 1))
         self.cache = T.init_paged_cache(cfg, n_pages, ps, batch=b)
+        _place(self, devices)
         # Per-leaf lane axis: recurrent-state leaves carry the lane (batch)
         # axis, page-pool leaves don't — locate it by shape difference
         # between a b-lane and a (b+1)-lane cache, sentinel -1 for pool

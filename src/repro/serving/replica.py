@@ -4,7 +4,7 @@ Tensor parallelism (``ServeConfig.tp``, ``runtime.tp_packed``) splits one
 engine's weights across a mesh; this module scales the OTHER axis —
 throughput — by running ``n_replicas`` complete engines side by side and
 routing requests between them.  The two compose: each replica may itself
-be a TP engine over its own mesh slice (DESIGN.md §4).
+be a TP engine over its own device slice (DESIGN.md §4).
 
 Routing is join-shortest-queue and fully deterministic: a request goes
 to the replica with the least load (``queued + running`` from
@@ -41,15 +41,21 @@ class ReplicaFront:
 
     ``engine_cls`` selects the replica engine (``Engine`` or
     ``ContinuousEngine``; both expose the same submit/step/outputs/stats
-    surface).
+    surface).  Replica ``r`` runs on devices ``[r·tp, (r+1)·tp)``
+    (``launch.mesh.replica_devices``), so the replicas of one host sit on
+    disjoint chips.
     """
 
     def __init__(self, cfg, params, serve_cfg: ServeConfig,
                  n_replicas: int = 2, engine_cls=Engine):
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
+        from ..launch.mesh import replica_devices
+
         self.replicas = [
-            engine_cls(cfg, params, serve_cfg) for _ in range(n_replicas)
+            engine_cls(cfg, params, serve_cfg,
+                       devices=replica_devices(r, serve_cfg.tp))
+            for r in range(n_replicas)
         ]
         self._route: dict[int, tuple[int, int]] = {}  # grid -> (rep, lrid)
         self._next_rid = 0

@@ -14,6 +14,7 @@ doesn't fake a win.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Callable, Sequence
 
@@ -35,28 +36,27 @@ __all__ = [
     "PHASE_BLOCKS",
 ]
 
-# MXU/VPU-aligned sweep grid; filtered per spec/problem by candidate_blocks.
+# Sweep grid, filtered per spec/problem by candidate_blocks.  Every block
+# compiles for the chip: bk a multiple of 256 (the kernel's activation
+# halves need 128-lane tiles), bn a multiple of 128, and bm at most 64 (the
+# unrolled VPU chunk loop's VMEM temporaries grow with bm).
 DEFAULT_BLOCKS = (
-    (128, 128, 128),
-    (128, 128, 256),
-    (128, 256, 128),
-    (256, 128, 128),
+    (32, 128, 256),
+    (32, 128, 512),
+    (32, 256, 256),
+    (16, 128, 256),
+    (16, 128, 512),
     (64, 128, 256),
-    (64, 128, 128),
-    (64, 64, 512),
-    (32, 128, 128),
 )
 
 # Decode-phase sweep grid: a decode step is a GEMV over the slot batch
-# (M of 1-16), so M blocks hug the batch instead of padding it 8-64x up to
-# an MXU tile; N/K blocks still sweep the weight-streaming axis.
+# (M of 1-16), so M blocks hug the batch instead of padding it up to a
+# prefill tile; N/K blocks still sweep the weight-streaming axis.
 DECODE_BLOCKS = (
-    (8, 128, 128),
     (8, 128, 256),
-    (8, 256, 128),
-    (8, 64, 256),
-    (16, 128, 128),
-    (16, 256, 128),
+    (8, 128, 512),
+    (8, 256, 256),
+    (16, 128, 256),
 )
 
 # The serving engine runs the same kernel in two regimes with very different
@@ -105,8 +105,8 @@ def candidate_blocks(
         return snug
     if legal:
         return [min(legal, key=lambda b: b[0] * b[1] * b[2])]
-    # every candidate's bk was smaller than one extraction chunk: build one
-    return [(min(128, max(8, m)), min(128, max(8, n)), spec.chunk)]
+    # no candidate's bk holds whole extraction chunks: build one that does
+    return [(8 if m <= 8 else 32, 128, math.lcm(256, spec.chunk))]
 
 
 def autotune_block(

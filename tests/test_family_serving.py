@@ -32,6 +32,7 @@ the full (family x mode) matrix and the tuned/mixed columns are slow-lane.
 
 import dataclasses
 import functools
+import gc
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +66,18 @@ MIXED_KW = dict(width_candidates=((4, 4), (8, 8)), calib_tokens=8)
 MAX_LEN = 32
 PROMPT = list(range(5, 14))
 N_NEW = 6
+
+
+@pytest.fixture(autouse=True)
+def _drop_compiled_executables():
+    """XLA:CPU's JIT segfaults once one process holds too many compiled
+    executables (tests/conftest.py).  Every test here compiles whole
+    engines, and the dsp_mixed sensitivity pass one eager program per
+    probe, so the process would reach that limit within this file:
+    release them before each test."""
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
 
 
 @functools.lru_cache(maxsize=None)

@@ -34,6 +34,7 @@ from dsp_sim import simulate_packed_matmul
 from repro.core.quantize import quantize_unsigned
 from repro.kernels import ref
 from repro.kernels.packed_matmul import (
+    DEFAULT_BLOCK,
     default_block_for,
     packed_matmul,
     packed_matmul_prepacked,
@@ -213,11 +214,13 @@ class TestPrepackedParity:
     )
     def test_fused_quantize_prologue_matches_staged(self, spec):
         """The in-kernel activation quantize (f32 tile + row scale) equals
-        quantize-then-call bit for bit — no HBM staging round-trip."""
+        quantize-then-call bit for bit — no HBM staging round-trip.  The
+        weights' K exceeds the activation's, so the padded tail must stay
+        transparent too."""
         rng = np.random.default_rng(7)
         m, k, n = 5, 2 * spec.chunk + 3, 12
         w = jnp.asarray(rng.integers(
-            -(1 << (spec.bits_w - 1)), 1 << (spec.bits_w - 1), (k, n)
+            -(1 << (spec.bits_w - 1)), 1 << (spec.bits_w - 1), (k + 5, n)
         ), jnp.int32)
         xf = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
         xq = quantize_unsigned(xf, bits=spec.bits_a, axis=-1)
@@ -235,10 +238,14 @@ class TestPrepackedParity:
     def test_decode_default_block_is_small_m(self):
         spec = PackedDotSpec(4, 4, 11, 4, "full")
         assert default_block_for(2, spec)[0] == 8
-        assert default_block_for(128, spec)[0] == 128
-        # chunk-aligned bk even for long-chunk plans
-        mr = PackedDotSpec(4, 4, 10, 16, "mr+full", 3)  # chunk 32
-        assert default_block_for(2, mr)[2] % mr.chunk == 0
+        assert default_block_for(128, spec)[0] == DEFAULT_BLOCK[0] > 8
+        # chunk-aligned bk even for long-chunk plans, and always 128-lane
+        # activation halves (what the chip's compiler requires)
+        for s in (spec, PackedDotSpec(4, 4, 10, 16, "mr+full", 3),
+                  PackedDotSpec(2, 2, 10, 3, "full")):  # chunks 8, 32, 6
+            for m in (2, 128):
+                bk = default_block_for(m, s)[2]
+                assert bk % s.chunk == 0 and (bk // 2) % 128 == 0
 
     def test_activation_shorter_than_packed_weights(self):
         """An x truncated well below the words' K — with a bk that does

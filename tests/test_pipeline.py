@@ -19,14 +19,14 @@ def test_two_stage_pipeline_matches_sequential(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from repro.models import transformer as T
         from repro.models.registry import get_config
-        from repro.runtime.jax_compat import use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.runtime.pipeline import pipeline_forward, split_stages
 
         cfg = dataclasses.replace(
             get_config("qwen1.5-110b", smoke=True), dtype="float32",
             remat="none",
         )
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         params = T.init_params(jax.random.PRNGKey(0), cfg)
 
         n_micro, mb, s = 3, 2, 16
@@ -40,7 +40,7 @@ def test_two_stage_pipeline_matches_sequential(tmp_path):
         ref = jnp.stack(ref)
 
         staged = split_stages(params, 2)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = pipeline_forward(staged, cfg, toks, mesh)
         err = float(jnp.abs(got - ref).max())
         assert err < 2e-3, err

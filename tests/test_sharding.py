@@ -128,7 +128,8 @@ def test_subprocess_8dev_dryrun_smoke(tmp_path):
         from repro.runtime.sharding import param_shardings
         from repro.launch.steps import make_train_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("qwen1.5-110b", smoke=True)
         params = T.init_params(jax.random.PRNGKey(0), cfg)
         shard = param_shardings(params, mesh)
@@ -172,7 +173,8 @@ def test_elastic_reshard_restore(tmp_path):
         ck = Checkpointer(r"{tmp_path}")
         ck.save(1, params)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         shard = param_shardings(params, mesh)
         restored, _ = ck.restore(1, params, shardings=shard)
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):

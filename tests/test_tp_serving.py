@@ -143,6 +143,33 @@ def test_illegal_sharding_rejected_with_clause():
     assert "REJECT_OK" in out and "KERNEL_REJECT_OK" in out
 
 
+def test_replicas_sit_on_their_own_device_slices():
+    """Replica r of a ReplicaFront runs on devices [r·tp, (r+1)·tp): four
+    tp=1 replicas on four devices hold their weights and cache on one
+    device each, two tp=2 replicas each span their own pair — and routing
+    never changes tokens."""
+    out = _run(4, """
+    from repro.serving import ReplicaFront
+
+    def devices_of(tree):
+        return {d.id for a in jax.tree.leaves(tree) for d in a.devices()}
+
+    ref = Engine(CFG, PARAMS, scfg(quant_mode="int4_packed")).generate(
+        PROMPTS, max_new=4)
+    for tp, n_rep in ((1, 4), (2, 2)):
+        front = ReplicaFront(CFG, PARAMS, scfg(quant_mode="int4_packed", tp=tp),
+                             n_replicas=n_rep)
+        for r, eng in enumerate(front.replicas):
+            want = set(range(r * tp, (r + 1) * tp))
+            assert devices_of(eng.params) == want, (tp, r)
+            assert devices_of(eng.cache) == want, (tp, r)
+        got = front.generate(PROMPTS * 2, max_new=4)
+        assert [got[g] for g in sorted(got)] == [ref[0], ref[1]] * 2, got
+        print("SLICES_OK", tp)
+    """)
+    assert "SLICES_OK 1" in out and "SLICES_OK 2" in out
+
+
 # ---- replica front (in-process: no mesh required) --------------------------
 
 CFG = dataclasses.replace(get_config("qwen1.5-110b", smoke=True),
