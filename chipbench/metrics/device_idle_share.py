@@ -1,0 +1,10 @@
+"""Percent of the traced window in which no operation ran on the device:
+1 - (union of the device's operation intervals) / window, from the
+profiler trace.  Moves ``out_tok_s``."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
