@@ -80,6 +80,9 @@ def flash_attention(
         out_specs=pl.BlockSpec((None, bq, hd), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), q.dtype),
         interpret=interpret,
+        # names the HLO instruction a device profile shows: the jitted
+        # wrapper's name, which the benchmark's kernel readers match
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, s, hd)
 

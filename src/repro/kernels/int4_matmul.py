@@ -90,5 +90,8 @@ def int4_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         interpret=interpret,
+        # names the HLO instruction a device profile shows: the jitted
+        # wrapper's name, which the benchmark's kernel readers match
+        name="int4_matmul",
     )(x_q[:, 0::2], x_q[:, 1::2], w_packed)
     return out[:m, :n]

@@ -294,5 +294,8 @@ def packed_matmul_prepacked(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         interpret=interpret,
+        # names the HLO instruction a device profile shows: the jitted
+        # wrapper's name, which the benchmark's kernel readers match
+        name="packed_matmul_prepacked",
     )(*operands)
     return out[:m, :n]

@@ -248,13 +248,25 @@ def main() -> None:
         print(f"[serve] request {rid}: {len(toks)} tokens ({reason}) "
               f"-> {toks[:8]}...")
     stats = engine.stats()
+    rates = (f"prefill {stats['prefill_tok_s']:.1f} tok/s, "
+             f"decode {stats['decode_tok_s']:.1f} tok/s")
+    if args.engine == "continuous":
+        # a continuous step runs both phases: each rate is over the time
+        # of the steps that carried its phase
+        rates = (f"{rates} over the steps carrying each, median step "
+                 f"{stats['median_step_s'] * 1e3:.0f}ms")
+    # the share of the rows the batched programs computed that were real
+    rows = ", ".join(
+        f"{phase} {100 * stats[f'{phase}_tokens'] / stats[f'{phase}_rows']:.0f}%"
+        for phase in ("prefill", "decode") if stats[f"{phase}_rows"])
     print(f"[serve] {total_tokens} tokens in {dt:.2f}s "
           f"(engine={args.engine}, quant={engine.scfg.quant_mode}, "
-          f"prefill {stats['prefill_tok_s']:.1f} tok/s, "
-          f"decode {stats['decode_tok_s']:.1f} tok/s, "
+          f"{rates}, "
           f"ttft p50 {stats['p50_ttft_s'] * 1e3:.0f}ms / "
           f"p99 {stats['p99_ttft_s'] * 1e3:.0f}ms, "
           f"mean latency {stats['mean_latency_s'] * 1e3:.0f}ms)")
+    if rows:
+        print(f"[serve] real rows: {rows}")
     if args.engine == "continuous":
         print(f"[serve] pages: {stats['n_pages'] - stats['free_pages']}"
               f"/{stats['n_pages']} in use at exit "

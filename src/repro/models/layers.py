@@ -148,34 +148,51 @@ def attention(
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
-    new_cache = None
     if cache is not None and "pages_k" in cache:
-        # paged/block KV cache: scatter this call's K/V through the lane's
-        # page table, gather the logical view back, attend with the dense
-        # validity mask.  Unallocated blocks and masked lanes carry the
-        # OOB sentinel (== n_pages): their writes are DROPPED (JAX OOB
-        # scatter semantics) and their gathered junk is masked to NEG_INF,
-        # whose exp underflows to exactly 0 — so paged attention is
-        # bit-identical to the dense cache over the valid positions.
-        assert page_table is not None, "paged cache needs a page_table"
-        pages_k, pages_v = cache["pages_k"], cache["pages_v"]
-        n_pages, ps = pages_k.shape[0], pages_k.shape[1]
-        max_blocks = page_table.shape[1]
-        if positions.ndim == 2:
-            row_pos = positions[:, 0]
-        else:
-            row_pos = jnp.broadcast_to(positions.reshape(-1)[:1], (b,))
-        pos = row_pos[:, None] + jnp.arange(s)[None]          # (B, S)
-        if cfg.sliding_window:
-            # ring-buffer pages: the logical slot wraps at the window, so a
-            # lane's pool is bounded by ceil(window/page_size) pages.  The
-            # serving engines keep chunk-1 prefill for sliding windows
-            # (chunked prefill over a ring overwrites slots still needed by
-            # earlier in-chunk queries), so s == 1 whenever wrapping can
-            # occur.
-            slot = pos % cfg.sliding_window
-        else:
-            slot = pos
+        # named scopes are HLO metadata only: a device profile attributes
+        # the paged path's time to its KV write, KV gather and scores
+        with jax.named_scope("paged_attention"):
+            out, new_cache = _paged_attention(
+                q, k, v, cache, cfg, positions, page_table, x.dtype
+            )
+    else:
+        out, new_cache = _dense_attention(
+            q, k, v, cache, cfg, positions, causal, kv_x, x.dtype
+        )
+    out = out.reshape(b, s, nh * hd)
+    return apply_linear(params["wo"], out, spec), new_cache
+
+
+def _paged_attention(q, k, v, cache, cfg, positions, page_table, dtype):
+    """Attention over a paged/block KV cache: scatter this call's K/V
+    through the lane's page table, gather the logical view back, attend
+    with the dense validity mask.  Unallocated blocks and masked lanes
+    carry the OOB sentinel (== n_pages): their writes are DROPPED (JAX OOB
+    scatter semantics) and their gathered junk is masked to NEG_INF, whose
+    exp underflows to exactly 0 — so paged attention is bit-identical to
+    the dense cache over the valid positions."""
+    assert page_table is not None, "paged cache needs a page_table"
+    b, s, nh, hd = q.shape
+    nkv = cfg.n_kv_heads
+    pages_k, pages_v = cache["pages_k"], cache["pages_v"]
+    n_pages, ps = pages_k.shape[0], pages_k.shape[1]
+    max_blocks = page_table.shape[1]
+    if positions.ndim == 2:
+        row_pos = positions[:, 0]
+    else:
+        row_pos = jnp.broadcast_to(positions.reshape(-1)[:1], (b,))
+    pos = row_pos[:, None] + jnp.arange(s)[None]          # (B, S)
+    if cfg.sliding_window:
+        # ring-buffer pages: the logical slot wraps at the window, so a
+        # lane's pool is bounded by ceil(window/page_size) pages.  The
+        # serving engines keep chunk-1 prefill for sliding windows
+        # (chunked prefill over a ring overwrites slots still needed by
+        # earlier in-chunk queries), so s == 1 whenever wrapping can
+        # occur.
+        slot = pos % cfg.sliding_window
+    else:
+        slot = pos
+    with jax.named_scope("kv_write"):
         blk = slot // ps
         page = jnp.take_along_axis(
             page_table, jnp.clip(blk, 0, max_blocks - 1), axis=1
@@ -190,29 +207,40 @@ def attention(
         pages_v = pages_v.at[page, off].set(
             v.astype(pages_v.dtype), mode="drop"
         )
-        new_cache = {"pages_k": pages_k, "pages_v": pages_v}
-        # gather the lane's logical view (invalid entries clamp to junk
-        # pages — masked below exactly like unwritten dense positions)
-        window = max_blocks * ps
+    new_cache = {"pages_k": pages_k, "pages_v": pages_v}
+    # gather the lane's logical view (invalid entries clamp to junk
+    # pages — masked below exactly like unwritten dense positions)
+    window = max_blocks * ps
+    with jax.named_scope("kv_gather"):
         k = pages_k[page_table].reshape(b, window, nkv, hd)
         v = pages_v[page_table].reshape(b, window, nkv, hd)
-        cache_positions = jnp.arange(window)
-        qidx = jnp.arange(s)
-        if cfg.sliding_window:
-            # every written ring slot is in-window (dense ring branch
-            # semantics); gathered slots past the ring are never written
-            ring = cfg.sliding_window
-            valid = (
-                (cache_positions[None, None, :] <= slot[:, :, None])
-                | (pos[:, :, None] >= ring)
-            ) & (cache_positions[None, None, :] < ring)
-        else:
-            valid = (
-                cache_positions[None, None, :]
-                <= row_pos[:, None, None] + qidx[None, :, None]
-            )
-        mask = jnp.where(valid[:, None, :, :], 0.0, NEG_INF)
-    elif cache is not None:
+        k = _repeat_kv(k, nh // nkv)
+        v = _repeat_kv(v, nh // nkv)
+    cache_positions = jnp.arange(window)
+    qidx = jnp.arange(s)
+    if cfg.sliding_window:
+        # every written ring slot is in-window (dense ring branch
+        # semantics); gathered slots past the ring are never written
+        ring = cfg.sliding_window
+        valid = (
+            (cache_positions[None, None, :] <= slot[:, :, None])
+            | (pos[:, :, None] >= ring)
+        ) & (cache_positions[None, None, :] < ring)
+    else:
+        valid = (
+            cache_positions[None, None, :]
+            <= row_pos[:, None, None] + qidx[None, :, None]
+        )
+    return _scores(q, k, v, valid, dtype, decode=True), new_cache
+
+
+def _dense_attention(q, k, v, cache, cfg, positions, causal, kv_x, dtype):
+    """Attention over a dense cache window (decode, or cached chunked
+    prefill), or over this call's own K/V when ``cache`` is None."""
+    b, s, nh, hd = q.shape
+    nkv = cfg.n_kv_heads
+    new_cache = None
+    if cache is not None:
         # decode (s==1) or cached chunked prefill (s>1, full attention only):
         # write K/V at each row's own position, attend over the cache.  Rows
         # (serving slots) may sit at different depths, so writes and masks
@@ -241,17 +269,15 @@ def attention(
                 cache_positions[None, None, :]
                 <= row_pos[:, None, None] + qidx[None, :, None]
             )
-        mask = jnp.where(valid[:, None, :, :], 0.0, NEG_INF)
     elif causal:
         ii = positions if positions.ndim == 2 else positions[None]
         qi = ii[:, :, None]
         ki = ii[:, None, :]
-        ok = ki <= qi
+        valid = ki <= qi
         if cfg.sliding_window:
-            ok &= ki > qi - cfg.sliding_window
-        mask = jnp.where(ok[:, None, :, :], 0.0, NEG_INF)
+            valid &= ki > qi - cfg.sliding_window
     else:
-        mask = None
+        valid = None
 
     k = _repeat_kv(k, nh // nkv)
     v = _repeat_kv(v, nh // nkv)
@@ -264,19 +290,28 @@ def attention(
         and s % cfg.attention_chunk == 0
     )
     if use_chunked:
-        out = _chunked_causal_attention(
-            q, k, v, positions, cfg.attention_chunk, cfg.sliding_window
-        )
-    else:
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * hd**-0.5
-        if cache is not None:
+        with jax.named_scope("scores"):
+            out = _chunked_causal_attention(
+                q, k, v, positions, cfg.attention_chunk, cfg.sliding_window
+            )
+        return out, new_cache
+    return _scores(q, k, v, valid, dtype, decode=cache is not None), new_cache
+
+
+def _scores(q, k, v, valid, dtype, decode: bool):
+    """Softmax(QK^T / sqrt(hd) + mask) V, with positions outside ``valid``
+    (B, S, K) bool masked to NEG_INF (``None``: attend everywhere)."""
+    with jax.named_scope("scores"):
+        mask = (None if valid is None
+                else jnp.where(valid[:, None, :, :], 0.0, NEG_INF))
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+                  * q.shape[-1]**-0.5)
+        if decode:
             scores = constrain(scores, "scores_decode")
         if mask is not None:
             scores = scores + mask
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    out = out.reshape(b, s, nh * hd)
-    return apply_linear(params["wo"], out, spec), new_cache
+        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def _chunked_causal_attention(q, k, v, positions, chunk: int, window: int | None):

@@ -274,7 +274,10 @@ def _apply_group(
             page_table=page_table,
         )
         x = add(x, h)
-        x = add(x, mlp(gp["mlp"], rmsnorm(gp["ln2"], x, cfg.norm_eps), spec))
+        h = rmsnorm(gp["ln2"], x, cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            y = mlp(gp["mlp"], h, spec)
+        x = add(x, y)
         return x, None if new_kv is None else {"attn": new_kv}, aux
 
     if fam == "moe":
@@ -302,7 +305,10 @@ def _apply_group(
             causal=False, kv_x=encoder_out,
         )
         x = add(x, h)
-        x = add(x, gelu_mlp(gp["mlp"], rmsnorm(gp["ln2"], x, cfg.norm_eps), spec))
+        h = rmsnorm(gp["ln2"], x, cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            y = gelu_mlp(gp["mlp"], h, spec)
+        x = add(x, y)
         return x, None if new_kv is None else {"attn": new_kv}, aux
 
     if fam == "ssm":
@@ -371,7 +377,10 @@ def _apply_group(
                 moe_i += 1
             else:
                 sub = jax.tree.map(lambda t: t[mlp_i], gp["mlp"])
-                x = add(x, mlp(sub, rmsnorm(ln_ffn, x, cfg.norm_eps), spec))
+                h = rmsnorm(ln_ffn, x, cfg.norm_eps)
+                with jax.named_scope("mlp"):
+                    y = mlp(sub, h, spec)
+                x = add(x, y)
                 mlp_i += 1
         new_cache = None
         if cache is not None:
@@ -494,10 +503,14 @@ def forward(
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if return_hidden:
         return x, new_cache, aux
-    if cfg.tie_embeddings:
-        logits = x.astype(logits_dtype) @ params["embed"]["w"].T.astype(logits_dtype)
-    else:
-        logits = apply_linear(params["lm_head"], x, cfg.quant).astype(logits_dtype)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = (x.astype(logits_dtype)
+                      @ params["embed"]["w"].T.astype(logits_dtype))
+        else:
+            logits = apply_linear(
+                params["lm_head"], x, cfg.quant
+            ).astype(logits_dtype)
     return logits, new_cache, aux
 
 

@@ -70,7 +70,7 @@ class StragglerDetector:
     O(window log window) over the retained window only, never the full
     history.  Besides the fleet-relative ``stragglers`` view, the
     single-stream ``rolling_median`` is the serving governor's slow-step
-    signal: the continuous engine records each decode step's wall time
+    signal: the continuous engine records each step's wall time
     under one host id and the governor compares the rolling median
     against its configured ceiling.
     """

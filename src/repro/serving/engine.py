@@ -60,6 +60,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core.packed_params import (
     SERVING_MODES,
@@ -835,7 +836,8 @@ class Engine:
             jnp.asarray(self._top_p),
         ))
         n_prompt_tokens = sum(len(r.prompt) for r in admitted)
-        self.scheduler.note_prefill(n_prompt_tokens, time.monotonic() - t0)
+        self.scheduler.note_prefill(n_prompt_tokens, time.monotonic() - t0,
+                                    rows=n_chunks * b * c)
         finished = []
         for slot, req in zip(free, admitted):
             tok = int(first[slot])
@@ -942,7 +944,8 @@ class Engine:
         )
         nxt = np.asarray(nxt)
         active_slots = np.flatnonzero(self.active)
-        self.scheduler.note_decode(len(active_slots), time.monotonic() - t0)
+        self.scheduler.note_decode(len(active_slots), time.monotonic() - t0,
+                                   rows=self.scfg.n_slots)
         n_finished = len(finished)
         for slot in active_slots:
             # numpy mirrors advance exactly like the device state did
@@ -1067,16 +1070,17 @@ class ContinuousEngine:
     def __init__(self, cfg: ModelConfig, params, serve_cfg: ServeConfig,
                  mixed_allocation=None, devices=None):
         """Arguments as for :class:`Engine`."""
-        (cfg, params, self.plan_table, self.mixed_allocation, float_params,
-         self.plan_db_stats) = _prepare_serving_params(
-            cfg, params, serve_cfg, mixed_allocation
-        )
+        with TraceAnnotation("serve.build.pack"):
+            (cfg, params, self.plan_table, self.mixed_allocation,
+             float_params, self.plan_db_stats) = _prepare_serving_params(
+                cfg, params, serve_cfg, mixed_allocation
+            )
         self.mesh, params = _shard_for_tp(params, serve_cfg, devices)
         self.cfg = cfg
         self.params = params
         self.scfg = serve_cfg
         _setup_governor(self, cfg, float_params, serve_cfg)
-        # per-step decode wall times feed the governor's slow-step signal
+        # step wall times (``step``) feed the governor's slow-step signal
         # (rolling median over the retained window); recorded whether or
         # not a governor is attached — stats() surfaces the median either
         # way (runtime.fault_tolerance.StragglerDetector, host id 0)
@@ -1115,8 +1119,10 @@ class ContinuousEngine:
         wm = (serve_cfg.watermark_pages
               if serve_cfg.watermark_pages is not None else b)
         self.alloc = PageAllocator(n_pages, ps, min(wm, n_pages - 1))
-        self.cache = T.init_paged_cache(cfg, n_pages, ps, batch=b)
-        _place(self, devices)
+        with TraceAnnotation("serve.build.kv_pool"):
+            self.cache = T.init_paged_cache(cfg, n_pages, ps, batch=b)
+        with TraceAnnotation("serve.build.place"):
+            _place(self, devices)
         # Per-leaf lane axis: recurrent-state leaves carry the lane (batch)
         # axis, page-pool leaves don't — locate it by shape difference
         # between a b-lane and a (b+1)-lane cache, sentinel -1 for pool
@@ -1164,6 +1170,11 @@ class ContinuousEngine:
         self._shared_key: tuple | None = None
         self._shared_ready = False
         self._shared_pending_rid = -1
+        # the step clock (``step``): when this step's last device wait
+        # returned, and (start, prefill, decode) of dispatched work that
+        # no wait has covered yet
+        self._t_synced: float | None = None
+        self._unsynced: tuple[float, bool, bool] | None = None
 
     # ---- jitted steps ---------------------------------------------------
     @partial(jax.jit, static_argnums=(0,))
@@ -1259,6 +1270,7 @@ class ContinuousEngine:
             cache, self._lane_axes,
         )
 
+    @partial(annotate_function, name="serve.decode.push")
     def _push_state(self, decode_mask) -> None:
         self._dev_state = jax.device_put({
             "tokens": self.last_token,
@@ -1348,10 +1360,11 @@ class ContinuousEngine:
             )
         if deadline_ms is None:
             deadline_ms = self.scfg.deadline_ms
-        rid = self.scheduler.submit(
-            prompt, max_new, sampling,
-            deadline_s=None if deadline_ms is None else deadline_ms / 1e3,
-        )
+        with TraceAnnotation("serve.submit", rid=self.scheduler.next_rid):
+            rid = self.scheduler.submit(
+                prompt, max_new, sampling,
+                deadline_s=None if deadline_ms is None else deadline_ms / 1e3,
+            )
         if admit:
             self._admit_new()
         return rid
@@ -1384,6 +1397,7 @@ class ContinuousEngine:
             need += 1  # CoW of the partial shared page the prefill writes
         return seq, start, blocks_total, need
 
+    @partial(annotate_function, name="serve.admit")
     def _admit_new(self) -> None:
         """Admit queued requests into free lanes, strictly FIFO: if the
         front request's pages would dip the free list below the watermark,
@@ -1401,46 +1415,48 @@ class ContinuousEngine:
                     or (not self.active.any() and need <= self.alloc.n_free)):
                 break
             req = self.scheduler.admit_front()
-            lane = int(free[0])
-            self.alloc.open_table(req.rid)
-            adopting = start > 0
-            if adopting:
-                self.alloc.adopt_shared(self._shared_key, req.rid)
-            self.alloc.grow(req.rid, blocks_total)
-            # CoW every block the prefill will write into (only a shared
-            # partial page ever actually copies)
-            for blk in range(start // self.alloc.page_size, blocks_total):
-                page, src = self.alloc.make_writable(req.rid, blk)
-                if src is not None:
-                    self.cache = self._copy_page(
-                        self.cache, jnp.int32(src), jnp.int32(page)
+            with TraceAnnotation("serve.admit.request", rid=req.rid):
+                lane = int(free[0])
+                self.alloc.open_table(req.rid)
+                adopting = start > 0
+                if adopting:
+                    self.alloc.adopt_shared(self._shared_key, req.rid)
+                self.alloc.grow(req.rid, blocks_total)
+                # CoW every block the prefill will write into (only a shared
+                # partial page ever actually copies)
+                for blk in range(start // self.alloc.page_size, blocks_total):
+                    page, src = self.alloc.make_writable(req.rid, blk)
+                    if src is not None:
+                        self.cache = self._copy_page(
+                            self.cache, jnp.int32(src), jnp.int32(page)
+                        )
+                if self._has_state:
+                    # the new occupant must start from zero recurrent state
+                    lane_mask = np.zeros(self.scfg.n_slots, bool)
+                    lane_mask[lane] = True
+                    self.cache = self._reset_lanes(
+                        self.cache, jnp.asarray(lane_mask)
                     )
-            if self._has_state:
-                # the new occupant must start from zero recurrent state
-                lane_mask = np.zeros(self.scfg.n_slots, bool)
-                lane_mask[lane] = True
-                self.cache = self._reset_lanes(
-                    self.cache, jnp.asarray(lane_mask)
-                )
-            self._lane_rid[lane] = req.rid
-            self.active[lane] = True
-            self._prefilling[lane] = True
-            self._seq[lane] = np.asarray(seq, np.int32)
-            self._n_seq[lane] = len(seq)
-            self._last_idx[lane] = len(seq) - 1
-            self.positions[lane] = start
-            self._temperature[lane] = req.sampling.temperature
-            self._top_k[lane] = req.sampling.top_k
-            self._top_p[lane] = req.sampling.top_p
-            self._keys[lane] = np.asarray(slot_key(self._base_key, req.rid))
-            if (self._shared_prefix is not None and not self._shared_ready
-                    and self._shared_pending_rid < 0
-                    and self._matches_prefix(req.prompt)):
-                # first matching request prefills the prefix for everyone;
-                # its pages are pinned once its prefill completes
-                self._shared_pending_rid = req.rid
-            self._dev_dirty = True
+                self._lane_rid[lane] = req.rid
+                self.active[lane] = True
+                self._prefilling[lane] = True
+                self._seq[lane] = np.asarray(seq, np.int32)
+                self._n_seq[lane] = len(seq)
+                self._last_idx[lane] = len(seq) - 1
+                self.positions[lane] = start
+                self._temperature[lane] = req.sampling.temperature
+                self._top_k[lane] = req.sampling.top_k
+                self._top_p[lane] = req.sampling.top_p
+                self._keys[lane] = np.asarray(slot_key(self._base_key, req.rid))
+                if (self._shared_prefix is not None and not self._shared_ready
+                        and self._shared_pending_rid < 0
+                        and self._matches_prefix(req.prompt)):
+                    # first matching request prefills the prefix for everyone;
+                    # its pages are pinned once its prefill completes
+                    self._shared_pending_rid = req.rid
+                self._dev_dirty = True
 
+    @partial(annotate_function, name="serve.prefill")
     def _prefill_step(self) -> list[int]:
         """One chunk of prefill for every prefilling lane.  Lanes whose
         last chunk landed sample their first token, get their TTFT stamp,
@@ -1448,27 +1464,31 @@ class ContinuousEngine:
         lanes = np.flatnonzero(self._prefilling)
         if len(lanes) == 0:
             return []
-        t0 = time.monotonic()
         b, c = self.scfg.n_slots, self._chunk
-        tokens = np.zeros((b, c), np.int32)
-        base = np.zeros(b, np.int32)
-        row_mask = np.zeros(b, bool)
-        n_tok = 0
-        for lane in lanes:
-            pos = int(self.positions[lane])
-            chunk = self._seq[lane][pos:pos + c]
-            tokens[lane, : len(chunk)] = chunk
-            base[lane] = pos
-            row_mask[lane] = True
-            n_tok += len(chunk)
-        self.cache, self._last_hidden = self._prefill_chunk(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(base),
-            jnp.asarray(self.alloc.table_array(
-                self._lane_rid, self._max_blocks
-            )),
-            jnp.asarray(row_mask), jnp.asarray(self._last_idx),
-            self._last_hidden,
-        )
+        with TraceAnnotation("serve.prefill.inputs"):
+            tokens = np.zeros((b, c), np.int32)
+            base = np.zeros(b, np.int32)
+            row_mask = np.zeros(b, bool)
+            n_tok = 0
+            for lane in lanes:
+                pos = int(self.positions[lane])
+                chunk = self._seq[lane][pos:pos + c]
+                tokens[lane, : len(chunk)] = chunk
+                base[lane] = pos
+                row_mask[lane] = True
+                n_tok += len(chunk)
+            inputs = (
+                jnp.asarray(tokens), jnp.asarray(base),
+                jnp.asarray(self.alloc.table_array(
+                    self._lane_rid, self._max_blocks
+                )),
+                jnp.asarray(row_mask), jnp.asarray(self._last_idx),
+            )
+        with TraceAnnotation("serve.prefill.dispatch"):
+            self.cache, self._last_hidden = self._prefill_chunk(
+                self.params, self.cache, *inputs, self._last_hidden,
+            )
+        self.scheduler.note_prefill(n_tok, rows=tokens.size)
         done_lanes = []
         for lane in lanes:
             if self.positions[lane] + c >= self._n_seq[lane]:
@@ -1480,12 +1500,14 @@ class ContinuousEngine:
         if done_lanes:
             # honest per-request TTFT: sync before stamping, and sample the
             # completed lanes' first tokens right now
-            first = np.asarray(self._sample(
-                self._lm_head(self.params, self._last_hidden),
-                jnp.asarray(self._keys), jnp.asarray(self._last_idx),
-                jnp.asarray(self._temperature), jnp.asarray(self._top_k),
-                jnp.asarray(self._top_p),
-            ))
+            with TraceAnnotation("serve.first_token"):
+                first = np.asarray(self._sample(
+                    self._lm_head(self.params, self._last_hidden),
+                    jnp.asarray(self._keys), jnp.asarray(self._last_idx),
+                    jnp.asarray(self._temperature), jnp.asarray(self._top_k),
+                    jnp.asarray(self._top_p),
+                ))
+            self._t_synced = time.monotonic()
             done_reqs = []
             for lane in done_lanes:
                 rid = int(self._lane_rid[lane])
@@ -1509,7 +1531,6 @@ class ContinuousEngine:
                 if fin is not None:
                     finished.append(fin)
             self.scheduler.note_prefill_done(done_reqs)
-        self.scheduler.note_prefill(n_tok, time.monotonic() - t0)
         # no _dev_dirty here: prefill touches no decode-lane state — lanes
         # that just completed join the decode mask next step, and that
         # membership change itself forces the state push (_decode_once)
@@ -1542,6 +1563,7 @@ class ContinuousEngine:
         self._seq.pop(lane, None)
         self._dev_dirty = True
 
+    @partial(annotate_function, name="serve.decode.pages")
     def _ensure_decode_pages(self, lanes: list[int]) -> None:
         """Grow each decoding lane's table to cover its next write; on
         ``OutOfPages`` preempt the youngest active request and retry
@@ -1589,13 +1611,13 @@ class ContinuousEngine:
                 )
                 self._dev_dirty = True
 
+    @partial(annotate_function, name="serve.decode")
     def _decode_once(self, decode_mask: np.ndarray) -> list[int]:
         """Advance the decode batch one token (lanes in ``decode_mask``
         that are still active — preemption may have evicted some)."""
         lanes = np.flatnonzero(decode_mask & self.active)
         if len(lanes) == 0:
             return []
-        t0 = time.monotonic()
         self._ensure_decode_pages([int(l) for l in lanes])
         lanes = np.flatnonzero(decode_mask & self.active)
         if len(lanes) == 0:
@@ -1608,24 +1630,26 @@ class ContinuousEngine:
         if (self._dev_dirty or self._pushed_mask is None
                 or not np.array_equal(mask, self._pushed_mask)):
             self._push_state(mask)
-        self.cache, self._dev_state, nxt = self._decode_step(
-            self.params, self.cache, self._dev_state
-        )
-        nxt = np.asarray(nxt)
-        dt = time.monotonic() - t0
-        self.scheduler.note_decode(len(lanes), dt)
-        self.straggler.record(0, dt)
+        with TraceAnnotation("serve.decode.dispatch"):
+            self.cache, self._dev_state, nxt = self._decode_step(
+                self.params, self.cache, self._dev_state
+            )
+        with TraceAnnotation("serve.decode.wait"):
+            nxt = np.asarray(nxt)
+        self._t_synced = time.monotonic()
+        self.scheduler.note_decode(len(lanes), rows=mask.size)
         finished = []
-        for lane in lanes:
-            self.positions[lane] += 1
-            tok = int(nxt[lane])
-            rid = int(self._lane_rid[lane])
-            self.scheduler.requests[rid].tokens.append(tok)
-            self._stream.append((rid, tok))
-            self.last_token[lane] = tok
-            fin = self._maybe_finish(int(lane), tok)
-            if fin is not None:
-                finished.append(fin)
+        with TraceAnnotation("serve.decode.emit"):
+            for lane in lanes:
+                self.positions[lane] += 1
+                tok = int(nxt[lane])
+                rid = int(self._lane_rid[lane])
+                self.scheduler.requests[rid].tokens.append(tok)
+                self._stream.append((rid, tok))
+                self.last_token[lane] = tok
+                fin = self._maybe_finish(int(lane), tok)
+                if fin is not None:
+                    finished.append(fin)
         if finished:
             self._dev_dirty = True
         return finished
@@ -1645,13 +1669,14 @@ class ContinuousEngine:
 
     def _finish_lane(self, lane: int, reason: str) -> int:
         rid = int(self._lane_rid[lane])
-        self.active[lane] = False
-        self._prefilling[lane] = False
-        self._lane_rid[lane] = -1
-        self._seq.pop(lane, None)
-        self.alloc.free(rid)  # shared pins survive via their permanent ref
-        self.scheduler.finish(rid, reason)
-        self._dev_dirty = True
+        with TraceAnnotation("serve.finish", rid=rid):
+            self.active[lane] = False
+            self._prefilling[lane] = False
+            self._lane_rid[lane] = -1
+            self._seq.pop(lane, None)
+            self.alloc.free(rid)  # shared pins survive via their permanent ref
+            self.scheduler.finish(rid, reason)
+            self._dev_dirty = True
         return rid
 
     def _release_rid(self, rid: int) -> None:
@@ -1718,13 +1743,36 @@ class ContinuousEngine:
         fits, prefill one chunk per prefilling lane, advance the decode
         batch one token.  A lane that completed its prefill this step
         decodes from the NEXT step (the decode batch is snapshotted
-        before the prefill phase).  Returns finished rids."""
+        before the prefill phase).  Returns finished rids.
+
+        The step is timed from its entry to the return of its last wait on
+        the device (the decode ids, else the first-token ids); a step that
+        dispatched work and waited on none is timed with the next one that
+        waits.  That time feeds the straggler detector and the time of
+        each phase the step carried (``stats``).  The step's layer
+        boundaries are profiler spans (``serve.admit``, ``serve.prefill``,
+        ``serve.decode`` and their ``serve.*`` children), each a few
+        microseconds when no profile is being taken."""
+        t0 = time.monotonic()
+        sched = self.scheduler
+        rows = (sched.prefill_rows, sched.decode_rows)
+        self._t_synced = None
         self._shed_expired()
         self._govern()
         self._admit_new()
         decode_mask = (self.active & ~self._prefilling).copy()
         finished = self._prefill_step()
         finished += self._decode_once(decode_mask)
+        start, prefill, decode = self._unsynced or (t0, False, False)
+        prefill |= sched.prefill_rows > rows[0]
+        decode |= sched.decode_rows > rows[1]
+        self._unsynced = None
+        if self._t_synced is not None:
+            dt = self._t_synced - start
+            sched.note_step(dt, prefill, decode)
+            self.straggler.record(0, dt)
+        elif prefill or decode:
+            self._unsynced = (start, prefill, decode)
         return finished
 
     def generate(self, prompts: list[list[int]], max_new: int | None = None,
@@ -1767,14 +1815,19 @@ class ContinuousEngine:
     def stats(self) -> dict:
         """``Engine.stats`` plus the page-pool gauges (total/free pages,
         page size, admission watermark) and the straggler detector's
-        rolling-median decode step time."""
+        rolling-median step time (``median_step_s``).
+
+        A step runs both phases, so ``prefill_tok_s`` and ``decode_tok_s``
+        are each phase's tokens over the summed time of the steps that
+        carried that phase (``step``): a step carrying both counts in
+        both, so neither is the rate of its phase alone."""
         s = self.scheduler.stats()
         s.update(
             n_pages=self.alloc.n_pages,
             free_pages=self.alloc.n_free,
             page_size=self.alloc.page_size,
             watermark_pages=self.alloc.watermark,
-            decode_median_step_s=self.straggler.rolling_median(),
+            median_step_s=self.straggler.rolling_median(),
         )
         if self.governor is not None:
             s["governor"] = dict(

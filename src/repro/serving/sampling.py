@@ -59,30 +59,33 @@ def sample_tokens(
     top_p: jax.Array,        # (B,) float32, 1.0 = off
 ) -> jax.Array:
     """Draw one token per row.  Rows with ``temperature == 0`` take argmax."""
-    logits = logits.astype(jnp.float32)
-    b, v = logits.shape
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        b, v = logits.shape
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
 
-    # top-k: keep values >= the k-th largest (ties may keep a few extra)
-    desc = -jnp.sort(-scaled, axis=-1)
-    kth = jnp.take_along_axis(
-        desc, jnp.clip(top_k - 1, 0, v - 1)[:, None], axis=-1
-    )
-    keep = jnp.where((top_k > 0)[:, None], scaled >= kth, True)
+        # top-k: keep values >= the k-th largest (ties may keep a few extra)
+        desc = -jnp.sort(-scaled, axis=-1)
+        kth = jnp.take_along_axis(
+            desc, jnp.clip(top_k - 1, 0, v - 1)[:, None], axis=-1
+        )
+        keep = jnp.where((top_k > 0)[:, None], scaled >= kth, True)
 
-    # top-p (nucleus): smallest prefix of the sorted distribution whose
-    # mass reaches top_p; the first token always survives
-    probs = jax.nn.softmax(scaled, axis=-1)
-    sp = -jnp.sort(-probs, axis=-1)
-    csum = jnp.cumsum(sp, axis=-1)
-    n_keep = jnp.maximum(jnp.sum(csum - sp < top_p[:, None], axis=-1), 1)
-    thr = jnp.take_along_axis(sp, (n_keep - 1)[:, None], axis=-1)
-    keep &= probs >= thr
+        # top-p (nucleus): smallest prefix of the sorted distribution whose
+        # mass reaches top_p; the first token always survives
+        probs = jax.nn.softmax(scaled, axis=-1)
+        sp = -jnp.sort(-probs, axis=-1)
+        csum = jnp.cumsum(sp, axis=-1)
+        n_keep = jnp.maximum(jnp.sum(csum - sp < top_p[:, None], axis=-1), 1)
+        thr = jnp.take_along_axis(sp, (n_keep - 1)[:, None], axis=-1)
+        keep &= probs >= thr
 
-    masked = jnp.where(keep, scaled, NEG_INF)
-    step_keys = jax.vmap(jax.random.fold_in)(keys, positions)
-    gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (v,), jnp.float32))(step_keys)
-    sampled = jnp.argmax(masked + gumbel, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature <= 0, greedy, sampled)
+        masked = jnp.where(keep, scaled, NEG_INF)
+        step_keys = jax.vmap(jax.random.fold_in)(keys, positions)
+        gumbel = jax.vmap(
+            lambda k: jax.random.gumbel(k, (v,), jnp.float32)
+        )(step_keys)
+        sampled = jnp.argmax(masked + gumbel, axis=-1).astype(jnp.int32)
+        return jnp.where(temperature <= 0, greedy, sampled)

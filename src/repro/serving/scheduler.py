@@ -120,6 +120,10 @@ class Scheduler:
         # throughput/latency counters
         self.prefill_tokens = 0
         self.decode_tokens = 0
+        # rows the batched programs computed, real or padding: a prefill
+        # chunk call is n_slots x chunk rows, a decode call n_slots rows
+        self.prefill_rows = 0
+        self.decode_rows = 0
         self.prefill_time_s = 0.0
         self.decode_time_s = 0.0
         self.n_finished = 0
@@ -197,12 +201,15 @@ class Scheduler:
         self.n_preempted += 1
 
     # ---- accounting ----------------------------------------------------
-    def note_prefill(self, n_tokens: int, dt_s: float) -> None:
+    def note_prefill(self, n_tokens: int, dt_s: float = 0.0,
+                     rows: int = 0) -> None:
         """Throughput counters only — TTFT stamping is per-request via
         ``note_prefill_done`` (a mixed batch must not charge short prompts
-        for the longest prompt's chunk time)."""
+        for the longest prompt's chunk time).  ``rows``: the rows the
+        prefill program computed for these ``n_tokens`` real ones."""
         self.prefill_tokens += n_tokens
         self.prefill_time_s += dt_s
+        self.prefill_rows += rows
 
     def note_prefill_done(self, reqs: list[Request]) -> None:
         """Stamp TTFT for requests whose own last prefill chunk just
@@ -213,9 +220,20 @@ class Scheduler:
             if req.prefill_done_at is None:
                 req.prefill_done_at = now
 
-    def note_decode(self, n_tokens: int, dt_s: float) -> None:
+    def note_decode(self, n_tokens: int, dt_s: float = 0.0,
+                    rows: int = 0) -> None:
         self.decode_tokens += n_tokens
         self.decode_time_s += dt_s
+        self.decode_rows += rows
+
+    def note_step(self, dt_s: float, prefill: bool, decode: bool) -> None:
+        """Time of an engine step that carried prefill and/or decode work
+        (the continuous engine runs both phases in one step and can time
+        only the step): charged in full to each phase it carried."""
+        if prefill:
+            self.prefill_time_s += dt_s
+        if decode:
+            self.decode_time_s += dt_s
 
     def finish(self, rid: int, reason: str) -> None:
         """Complete a request.  A still-queued rid (never admitted, or
@@ -300,6 +318,8 @@ class Scheduler:
             "preempted": self.n_preempted,
             "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
+            "prefill_rows": self.prefill_rows,
+            "decode_rows": self.decode_rows,
             "prefill_tok_s": _rate(self.prefill_tokens, self.prefill_time_s),
             "decode_tok_s": _rate(self.decode_tokens, self.decode_time_s),
             "mean_ttft_s": _mean(ttft),
