@@ -172,7 +172,7 @@ def _paged_attention(q, k, v, cache, cfg, positions, page_table, dtype):
     exp underflows to exactly 0 — so paged attention is bit-identical to
     the dense cache over the valid positions."""
     assert page_table is not None, "paged cache needs a page_table"
-    b, s, nh, hd = q.shape
+    b, s, _, hd = q.shape
     nkv = cfg.n_kv_heads
     pages_k, pages_v = cache["pages_k"], cache["pages_v"]
     n_pages, ps = pages_k.shape[0], pages_k.shape[1]
@@ -214,8 +214,6 @@ def _paged_attention(q, k, v, cache, cfg, positions, page_table, dtype):
     with jax.named_scope("kv_gather"):
         k = pages_k[page_table].reshape(b, window, nkv, hd)
         v = pages_v[page_table].reshape(b, window, nkv, hd)
-        k = _repeat_kv(k, nh // nkv)
-        v = _repeat_kv(v, nh // nkv)
     cache_positions = jnp.arange(window)
     qidx = jnp.arange(s)
     if cfg.sliding_window:
@@ -279,8 +277,6 @@ def _dense_attention(q, k, v, cache, cfg, positions, causal, kv_x, dtype):
     else:
         valid = None
 
-    k = _repeat_kv(k, nh // nkv)
-    v = _repeat_kv(v, nh // nkv)
     use_chunked = (
         cache is None
         and kv_x is None
@@ -292,7 +288,8 @@ def _dense_attention(q, k, v, cache, cfg, positions, causal, kv_x, dtype):
     if use_chunked:
         with jax.named_scope("scores"):
             out = _chunked_causal_attention(
-                q, k, v, positions, cfg.attention_chunk, cfg.sliding_window
+                q, _repeat_kv(k, nh // nkv), _repeat_kv(v, nh // nkv),
+                positions, cfg.attention_chunk, cfg.sliding_window,
             )
         return out, new_cache
     return _scores(q, k, v, valid, dtype, decode=cache is not None), new_cache
@@ -300,18 +297,31 @@ def _dense_attention(q, k, v, cache, cfg, positions, causal, kv_x, dtype):
 
 def _scores(q, k, v, valid, dtype, decode: bool):
     """Softmax(QK^T / sqrt(hd) + mask) V, with positions outside ``valid``
-    (B, S, K) bool masked to NEG_INF (``None``: attend everywhere)."""
+    (B, S, K) bool masked to NEG_INF (``None``: attend everywhere).
+
+    ``k``/``v`` keep their own ``n_kv_heads``: with R = n_heads / n_kv_heads
+    > 1 the query is grouped as (B, S, G, R, hd), so query head h reads KV
+    head h // R (``_repeat_kv``'s order) and no repeated copy of the KV
+    window is ever written."""
+    b, s, nh, hd = q.shape
+    r = nh // k.shape[2]
     with jax.named_scope("scores"):
-        mask = (None if valid is None
-                else jnp.where(valid[:, None, :, :], 0.0, NEG_INF))
-        scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-                  * q.shape[-1]**-0.5)
+        if r > 1:
+            q = q.reshape(b, s, k.shape[2], r, hd)
+            qk, pv = "bqgrd,bkgd->bgrqk", "bgrqk,bkgd->bqgrd"
+        else:
+            qk, pv = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
+        scores = jnp.einsum(qk, q, k).astype(jnp.float32) * hd**-0.5
         if decode:
             scores = constrain(scores, "scores_decode")
-        if mask is not None:
-            scores = scores + mask
+        if valid is not None:
+            # (B, S, K) over the head axes of (B, [G, R | H], S, K)
+            mask = jnp.where(valid, 0.0, NEG_INF)
+            scores = scores + jnp.expand_dims(
+                mask, tuple(range(1, scores.ndim - 2))
+            )
         probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        return jnp.einsum(pv, probs, v).reshape(b, s, nh, hd)
 
 
 def _chunked_causal_attention(q, k, v, positions, chunk: int, window: int | None):

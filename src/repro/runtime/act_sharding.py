@@ -58,11 +58,11 @@ def activation_sharding(mesh: Mesh, group_shardings=None):
         if kind == "expert":  # (E, C, D)
             e = "model" if shape[0] % n_model == 0 else None
             return P(e, None, None)
-        if kind == "scores_decode":  # (B, H, q, S): shard the key axis so
-            # softmax runs distributed (psum of lse) instead of XLA
-            # gathering the whole KV cache per decoded token
+        if kind == "scores_decode":  # (B, H, q, S) or grouped (B, G, R, q, S):
+            # shard the key axis so softmax runs distributed (psum of lse)
+            # instead of XLA gathering the whole KV cache per decoded token
             s = "model" if shape[-1] % n_model == 0 else None
-            return P(batch, None, None, s)
+            return P(batch, *(None,) * (len(shape) - 2), s)
         return None
 
     _STATE.policy = (mesh, spec_for, group_shardings)

@@ -56,6 +56,14 @@ def test_scores_decode_seq_sharded():
     assert spec == P("data", None, None, "model")
 
 
+def test_scores_decode_grouped_seq_sharded():
+    # grouped-query scores (B, G, R, q, S): batch first, key axis last
+    spec = _spec_for(MESH, "scores_decode", (128, 8, 4, 1, 32768))
+    assert spec == P("data", None, None, None, "model")
+    spec = _spec_for(MESH, "scores_decode", (7, 4, 9, 16, 4100))
+    assert spec == P(None, None, None, None, None)
+
+
 def test_constrain_is_noop_without_policy():
     x = jnp.ones((4, 4))
     assert constrain(x, "residual") is x

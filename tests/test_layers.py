@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.models.config import ModelConfig
 from repro.models import layers as L
@@ -96,6 +97,81 @@ def test_gqa_head_broadcast():
     out = L._repeat_kv(x, 3)
     assert out.shape == (1, 2, 6, 4)
     np.testing.assert_array_equal(np.asarray(out[:, :, 0]), np.asarray(out[:, :, 2]))
+
+
+def _repeat_then_attend(q, k, v, valid, dtype):
+    """The repeat-then-attend form grouped-query ``_scores`` replaces:
+    every KV head copied n_heads / n_kv_heads times, then plain MHA."""
+    r = q.shape[2] // k.shape[2]
+    k, v = L._repeat_kv(k, r), L._repeat_kv(v, r)
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+              * q.shape[-1]**-0.5)
+    if valid is not None:
+        scores = scores + jnp.where(valid[:, None, :, :], 0.0, L.NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("masked", [True, False])
+@pytest.mark.parametrize("s", [1, 4])
+@pytest.mark.parametrize("nh,nkv", [(4, 4), (8, 2), (6, 3), (9, 1)])
+def test_grouped_scores_match_repeat_then_attend(nh, nkv, s, masked, dtype):
+    dtype = jnp.dtype(dtype)
+    b, t, hd = 3, 10, 16
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(nh * 10 + nkv), 3)
+    q = jax.random.normal(kq, (b, s, nh, hd)).astype(dtype)
+    k = jax.random.normal(kk, (b, t, nkv, hd)).astype(dtype)
+    v = jax.random.normal(kv, (b, t, nkv, hd)).astype(dtype)
+    # per-row validity: each row sits at its own depth, causal within s
+    depth = jnp.array([2, 5, t - s])
+    valid = (jnp.arange(t)[None, None, :]
+             <= depth[:, None, None] + jnp.arange(s)[None, :, None])
+    valid = valid if masked else None
+    got = L._scores(q, k, v, valid, dtype, decode=True)
+    ref = _repeat_then_attend(q, k, v, valid, dtype)
+    assert got.shape == ref.shape == (b, s, nh, hd) and got.dtype == dtype
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, ref, rtol=0, atol=1e-5)
+    else:
+        # within 2 bf16 ulp of the reference value (8 significand bits)
+        ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(ref), 2.0**-126))) - 7)
+        assert np.all(np.abs(got - ref) <= 2 * ulp)
+
+
+def _jaxpr_shapes(jaxpr):
+    """Every intermediate's shape, nested jaxprs (pjit, scans) included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(var.aval.shape)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _jaxpr_shapes(inner)
+
+
+@pytest.mark.parametrize("s", [1, 4])
+def test_paged_gqa_attention_never_repeats_kv(s):
+    """GQA paged attention gathers each lane's KV window at n_kv_heads and
+    never writes it out again at n_heads."""
+    cfg = CFG  # 4 query heads over 2 KV heads
+    b, ps, max_blocks = 2, 4, 3
+    window, nh, nkv, hd = max_blocks * ps, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    p = L.init_attention(jax.random.PRNGKey(5), cfg)
+    cache = L.init_paged_kv_cache(cfg, n_pages=b * max_blocks, page_size=ps,
+                                  dtype=jnp.float32)
+    page_table = jnp.arange(b * max_blocks, dtype=jnp.int32).reshape(b, max_blocks)
+    x = jnp.ones((b, s, cfg.d_model))
+    pos = jnp.array([[3], [5]]) + jnp.arange(s)[None]
+    jaxpr = jax.make_jaxpr(
+        lambda p, x, pos, c, pt: L.attention(p, x, cfg, pos, cache=c, page_table=pt)
+    )(p, x, pos, cache, page_table).jaxpr
+    shapes = set(_jaxpr_shapes(jaxpr))
+    assert (b, window, nkv, hd) in shapes  # the gather is seen
+    assert (b, window, nh, hd) not in shapes
+    assert (b, window, nkv, nh // nkv, hd) not in shapes
 
 
 def test_cached_prefill_multitoken():
