@@ -3,16 +3,22 @@
 1. refuse to run without the chips the cell asks for (no CPU fallback);
 2. turn on JAX's persistent compile cache at a fixed path in the checkout;
 3. build the configuration through the user's path — ``get_config``,
-   the weights (``weights.py``: made on the device from the seed in one
-   jitted call), ``ServeConfig``, the engine — and drop the bf16 tree;
+   the weights (the configuration's family module: made on the device
+   from the seed in one jitted call), ``ServeConfig``, the engine — and
+   drop the bf16 tree;
 4. warm up by running the cell's own traffic for the mix's
-   ``warmup_steps`` (every program the window runs is compiled there);
+   ``warmup_steps`` (every program the window runs is compiled there;
+   with ``trace``, the argument shapes of the engine's jitted programs
+   are noted as they are called, ``Programs``);
 5. run the closed loop for ``seconds``: each client submits its next
    request as soon as its previous one finishes; every token is stamped
    with the return time of the ``step()`` that emitted it;
 6. with ``trace``, profile the first ``TRACE_SECONDS`` of the window and
    read the per-layer metrics from it (the window is lengthened by the
-   time the profiler takes to write the trace);
+   time the profiler takes to write the trace); once the window has
+   closed, the optimized HLO text of each program the engine ran is taken
+   from what JAX compiled for the warm-up, so ``trace.reduce`` can give
+   device time to named scopes;
 7. check what the window served against the plain reference
    (``reference.py``) on a sample of finished requests drawn from the seed.
 
@@ -35,7 +41,7 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from . import reference, roofline, weights
+from . import reference, roofline
 from .bench import ROOT, Cell
 from .traffic import derive, requests
 
@@ -91,6 +97,7 @@ class Loop:
         self.stream = stream
         self.recs: dict[int, Rec] = {}
         self.decode_calls = 0
+        self.prefill_steps = 0
         self.first_tokens = 0
         self.steps = 0
         self.step_times: list[float] = []
@@ -105,7 +112,7 @@ class Loop:
 
     def step(self) -> float:
         sched = self.engine.scheduler
-        decoded = sched.decode_tokens
+        decoded, prefill_rows = sched.decode_tokens, sched.prefill_rows
         with self.span("chipbench.step"):
             finished = self.engine.step()
         t = time.perf_counter()
@@ -117,13 +124,76 @@ class Loop:
         self.steps += 1
         self.step_times.append(t)
         self.decode_calls += sched.decode_tokens > decoded
+        self.prefill_steps += sched.prefill_rows > prefill_rows
         for rid in finished:
             self.recs[rid].t_done = t
             self.submit(self.recs[rid].client)
         return t
 
     def counters(self) -> dict:
-        return {"prefill_tokens": self.engine.scheduler.prefill_tokens}
+        sched = self.engine.scheduler
+        return {"prefill_tokens": sched.prefill_tokens,
+                "prefill_rows": sched.prefill_rows,
+                "prefill_steps": self.prefill_steps}
+
+
+class Programs:
+    """The engine's jitted programs, as the warm-up calls them: each call
+    goes through a recorder that notes its arguments' shapes, types and
+    shardings.  ``stop`` puts the engine's own programs back before the
+    window opens; after the window ``texts`` lowers each program again at
+    the noted shapes and takes the compiled program's HLO text (JAX finds
+    the program it compiled for the warm-up: nothing compiles again)."""
+
+    def __init__(self, engine):
+        import jax
+
+        self.engine = engine
+        self.shapes: dict[str, tuple] = {}
+        members = {k: v for c in reversed(type(engine).__mro__)
+                   for k, v in vars(c).items()}
+        self._programs = {
+            name: fn for name, fn in {**members, **vars(engine)}.items()
+            if callable(fn) and hasattr(fn, "lower")}
+        # the programs the engine holds itself, not through its class
+        self._own = {name: vars(engine)[name] for name in self._programs
+                     if name in vars(engine)}
+
+        def shape(x):
+            # an argument placed on no device by its caller lowers as one
+            # with no sharding: the same program, found under the same key
+            if isinstance(x, jax.Array):
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, weak_type=x.weak_type,
+                    sharding=x.sharding if x.committed else None)
+            return x
+
+        for name in self._programs:
+            call = getattr(engine, name)
+
+            def record(*args, _name=name, _call=call, **kwargs):
+                self.shapes[_name] = jax.tree.map(shape, (args, kwargs))
+                return _call(*args, **kwargs)
+
+            setattr(engine, name, record)
+
+    def stop(self) -> None:
+        for name in self._programs:
+            if name in self._own:
+                setattr(self.engine, name, self._own[name])
+            else:
+                delattr(self.engine, name)
+
+    def texts(self) -> dict[str, str]:
+        """HLO module name (``jit__decode_step``) -> optimized HLO text."""
+        out = {}
+        for name, (args, kwargs) in self.shapes.items():
+            fn = self._programs[name]
+            if name not in self._own:   # a method: ``self`` is static
+                args = (self.engine,) + tuple(args)
+            text = fn.lower(*args, **kwargs).compile().as_text()
+            out[text.split(None, 2)[1].rstrip(",")] = text
+        return out
 
 
 def _delta(a: dict, b: dict) -> dict:
@@ -143,15 +213,17 @@ def end_to_end(recs, lo: float, hi: float) -> dict:
     }
 
 
-def model_flops(model: dict, recs, steps, lo: float, hi: float) -> float:
+def model_flops(family, model: dict, recs, steps, lo: float,
+                hi: float) -> float:
     """Model FLOPs of the real tokens processed in (lo, hi]: each decoded
     position and the LM head once per emitted token, when the token lands
     there; a request's prompt spread evenly over the engine steps from its
     submit to its first token (``steps``: the return time of every step;
     a prefill in chunks runs all through them), and the share of those
     steps inside (lo, hi] counted.  Counting steps, not seconds, keeps a
-    compile inside the warm-up from moving prompt work out of the window."""
-    head = roofline.lm_head_flops(model)
+    compile inside the warm-up from moving prompt work out of the window.
+    ``family`` (a family module) counts the FLOPs."""
+    head = family.lm_head_flops(model)
     steps = np.asarray(steps)
     total = 0.0
     for r in recs:
@@ -159,11 +231,11 @@ def model_flops(model: dict, recs, steps, lo: float, hi: float) -> float:
             mine = steps[(steps > r.t_submit) & (steps <= r.times[0])]
             if mine.size:
                 share = np.count_nonzero((mine > lo) & (mine <= hi))
-                total += (roofline.prompt_flops(model, r.prompt_len)
+                total += (family.prompt_flops(model, r.prompt_len)
                           * share / mine.size)
         for j, t in enumerate(r.times):
             if lo < t <= hi:
-                total += head + (roofline.position_flops(
+                total += head + (family.position_flops(
                     model, r.prompt_len + j - 1) if j else 0)
     return total
 
@@ -205,11 +277,11 @@ def build(cell: Cell, seed: int):
     from repro.models.registry import get_config
     from repro.serving import ContinuousEngine, Engine, ServeConfig
 
-    conf = cell.config
+    conf, family = cell.config, cell.family
     cfg = dataclasses.replace(get_config(conf["arch"]), **conf["model"])
     wseed = derive(seed, "weights")
-    params = weights.params(reference.Model.from_config(conf["model"]),
-                            jax.random.PRNGKey(wseed))
+    params = family.params(family.Model.from_config(conf["model"]),
+                           jax.random.PRNGKey(wseed))
     scfg = ServeConfig(**conf["serve"], seed=wseed)
     engine_cls = {"slot": Engine, "continuous": ContinuousEngine}[
         conf["engine"]]
@@ -256,11 +328,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     t_warm = time.perf_counter()
     stream = requests(mix, seed, model["vocab_size"])
     loop = Loop(engine, stream)
+    programs = Programs(engine) if trace else None
     for c in range(int(mix["clients"])):
         loop.submit(c)
     while (loop.steps < mix["warmup_steps"] or not loop.decode_calls
            or not loop.first_tokens):
         loop.step()
+    if programs:
+        programs.stop()
     log(f"set-up: {t_build - t0:.3f}s to the device, build "
         f"{t_warm - t_build:.3f}s, warm-up {time.perf_counter() - t_warm:.3f}s"
         f" ({loop.steps} steps, {loop.decode_calls} decode calls, "
@@ -316,13 +391,27 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if trace:
         from . import trace as tracemod
 
+        t_hlo = time.perf_counter()
+        hlo = programs.texts()
+        log(f"compiled text of {sorted(hlo)} in "
+            f"{time.perf_counter() - t_hlo:.3f}s")
         paths = sorted(Path(tmp).rglob("*.xplane.pb"))
-        red = tracemod.reduce(tracemod.load(paths[-1])) if paths else {}
+        red = (tracemod.reduce(tracemod.load(paths[-1]), hlo=hlo)
+               if paths else {})
         shutil.rmtree(tmp, ignore_errors=True)
+        for prog, share in red.get("scope_matched", {}).items():
+            runs = red["module_runs"][prog]
+            top = sorted(red["scopes_s"][prog].items(), key=lambda kv: -kv[1])
+            log(f"scopes of {prog}: {runs} runs, {100 * share:.2f}% of its "
+                "device time joined; ms per run: " + ", ".join(
+                    f"{path or '(none)'} {1e3 * d / runs:.3f}"
+                    for path, d in top))
         ctx = SimpleNamespace(
-            model=model, serve=conf["serve"], engine=conf["engine"],
-            window_s=t_trace - t_open, counters=traced,
-            flops=model_flops(model, recs, loop.step_times, t_open, t_trace),
+            model=model, family=cell.family, serve=conf["serve"],
+            engine=conf["engine"], window_s=t_trace - t_open,
+            counters=traced,
+            flops=model_flops(cell.family, model, recs, loop.step_times,
+                              t_open, t_trace),
             peaks=peak_table,
             memory={"in_use": in_use, "limit": limit}, trace=red or None,
         )
@@ -354,7 +443,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                     (r.t_done is None or r.t_done > t_open) for r in recs)
     failed = sum(r.finish_reason in ("cancelled", "deadline")
                  for r in requests_.values())
-    del engine, loop, requests_
+    del engine, loop, requests_, programs
     _free()
     t_ref = time.perf_counter()
     limit = conf["check"]["logit_gap"]
@@ -366,7 +455,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     for control in (None,) + tuple(controls) if seqs else ():
         with jax.default_matmul_precision("highest"):
             got = reference.gaps(
-                model, wseed, seqs, n_rows=check["requests"],
+                cell.family, model, wseed, seqs, n_rows=check["requests"],
                 seq_len=mix["prompt"]["max"] + mix["output"]["max"],
                 control=control)
         if control is None:

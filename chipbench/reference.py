@@ -4,10 +4,11 @@ Written from the model's equations in ``jax.numpy`` and float32 (every
 float matmul at ``Precision.HIGHEST``), with no cache, kernel or batching
 of the program.  It imports nothing of the program and takes nothing the
 program made: it draws the weights anew from the seed, layer by layer,
-with the benchmark's own ``weights.py`` (which also made the tree the
-program serves), and quantizes them itself as the configuration states: weights to int4 per output channel
-(absmax / 7), activations to int8 per row (absmax / 127) before every
-linear, with exact integer products.
+with the configuration's family module (``families/<family>.py``, which
+also made the tree the program serves), and quantizes them as the
+configuration states: weights to int4 per output channel (absmax / 7),
+activations to int8 per row (absmax / 127) before every linear, with
+exact integer products.
 
 ``gaps`` runs the reference over each sampled request's prompt and served
 tokens and returns, for every served token, how far that token's logit
@@ -17,65 +18,26 @@ precision below the int8 activations the configuration states, int4
 activations into every linear, whose own first choices are read against
 the float32 reference the same way.
 
-The model follows the program's dense decoder: RMSNorm (scale ones),
-rotary embeddings on half-split heads, grouped-query causal attention
-(windowed when the model has a window), SwiGLU or tanh-GELU MLP, untied
+What is here is the same for every family: the quantized linear, the
+norm and rotary embeddings a family's layer may use, and the loop
+embedding -> the family's layers -> final RMSNorm (scale ones) and untied
 LM head.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights
-
 HIGHEST = jax.lax.Precision.HIGHEST
 # activation bits into every linear
 REFERENCE = 8
 CONTROLS = {"int4": 4}   # the step below the stated int8 activations
-Q_BLOCK = 512     # query rows per attention block
+Q_BLOCK = 512     # every sequence is padded to a multiple of this
 ROW_BLOCK = 512   # LM-head rows per block
-NEG = -1e30
-
-
-@dataclasses.dataclass(frozen=True)
-class Model:
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    d_ff: int
-    vocab_size: int
-    head_dim: int | None = None
-    qkv_bias: bool = False
-    rope_theta: float = 10_000.0
-    sliding_window: int | None = None
-    norm_eps: float = 1e-5
-    mlp_variant: str = "swiglu"
-
-    @classmethod
-    def from_config(cls, model: dict) -> "Model":
-        names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in model.items() if k in names})
-
-    @property
-    def hd(self) -> int:
-        return self.head_dim or self.d_model // self.n_heads
-
-
-# ---- weights, drawn from the seed ------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def layer_weights(m: Model, seed_key, i):
-    """Layer ``i``'s bf16 matmul weights, drawn as ``weights.params``
-    draws them (the qkv biases are zeros)."""
-    return weights.layer(m, weights.layer_keys(m, seed_key)[i])
 
 
 def quantize_weight(w):
@@ -88,10 +50,11 @@ def quantize_weight(w):
 # ---- forward ---------------------------------------------------------------
 
 
-def qlinear(x, wq, act_bits: int):
+def qlinear(x, w, act_bits: int):
     """x (R, K) f32 through activations quantized per row to ``act_bits``
-    and the int4 weight, with exact integer products."""
-    values, w_scale = wq
+    and the int4 weight ``w`` (``quantize_weight``'s pair), with exact
+    integer products."""
+    values, w_scale = w
     qmax = (1 << (act_bits - 1)) - 1
     s = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True), 1e-8) / qmax
     xq = jnp.clip(jnp.round(x / s), -qmax - 1, qmax).astype(jnp.int8)
@@ -113,64 +76,8 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def attention(m: Model, q, k, v):
-    """Causal grouped-query attention of one sequence, in query blocks.
-    q (S, H, hd); k, v (S, KV, hd)."""
-    s, h, hd = q.shape
-    rep = h // m.n_kv_heads
-    qb = min(Q_BLOCK, s)
-    kpos = jnp.arange(s)
-
-    def block(i):
-        qi = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, 0)
-        qi = qi.reshape(qb, m.n_kv_heads, rep, hd)
-        sc = jnp.einsum("qgrd,kgd->grqk", qi, k, precision=HIGHEST) * hd**-0.5
-        qpos = i * qb + jnp.arange(qb)
-        ok = kpos[None, :] <= qpos[:, None]
-        if m.sliding_window:
-            ok &= kpos[None, :] > qpos[:, None] - m.sliding_window
-        p = jax.nn.softmax(jnp.where(ok, sc, NEG), axis=-1)
-        o = jnp.einsum("grqk,kgd->qgrd", p, v, precision=HIGHEST)
-        return o.reshape(qb, h * hd)
-
-    return jax.lax.map(block, jnp.arange(s // qb)).reshape(s, h * hd)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def layer(m: Model, x, w, bits=REFERENCE):
-    """One decoder layer over x (B, S, d) f32, one sequence at a time, with
-    ``bits``-bit activations into every linear (``REFERENCE`` or one of
-    ``CONTROLS``)."""
-    hd = m.hd
-    lin = lambda a, name: qlinear(a, w[name], bits)
-
-    def one(xs):
-        s = xs.shape[0]
-        pos = jnp.arange(s)
-        h = rmsnorm(xs, m.norm_eps)
-        q = lin(h, "wq").reshape(s, m.n_heads, hd)
-        k = lin(h, "wk").reshape(s, m.n_kv_heads, hd)
-        v = lin(h, "wv").reshape(s, m.n_kv_heads, hd)
-        # the qkv biases are drawn as zeros: adding them changes nothing
-        q, k = rope(q, pos, m.rope_theta), rope(k, pos, m.rope_theta)
-        xs = xs + lin(attention(m, q, k, v), "wo")
-        h = rmsnorm(xs, m.norm_eps)
-        if m.mlp_variant == "gelu":
-            f = jax.nn.gelu(lin(h, "up"), approximate=True)
-        else:
-            f = jax.nn.silu(lin(h, "gate")) * lin(h, "up")
-        return xs + lin(f, "down")
-
-    return jax.lax.map(one, x)
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _quantize_layer(m: Model, w):
-    return {name: quantize_weight(a) for name, a in w.items()}
-
-
 @functools.partial(jax.jit, static_argnums=(0, 6))
-def _head_gaps(m: Model, x_ref, head, x_ctl, rows, toks, ctl=None):
+def _head_gaps(m, x_ref, head, x_ctl, rows, toks, ctl=None):
     """Per served token: the float32 reference's best logit minus its logit
     at the served token, its best minus its second best, and (with
     ``x_ctl``, the final hidden states with ``ctl``-bit activations) its
@@ -202,16 +109,18 @@ def _pad_to(n: int, mult: int) -> int:
     return max(mult, -(-n // mult) * mult)
 
 
-def gaps(model: dict, weight_seed: int, seqs, n_rows: int, seq_len: int,
-         control: str | None = None) -> dict:
+def gaps(family, model: dict, weight_seed: int, seqs, n_rows: int,
+         seq_len: int, control: str | None = None) -> dict:
     """Read the served tokens of ``seqs`` — (prompt, served tokens) pairs,
-    at most ``n_rows`` of them — against the reference.
+    at most ``n_rows`` of them — against the reference of ``family`` (a
+    family module) at the configuration's ``model``.
 
     ``seq_len`` pads every sequence to one fixed length (the mix's longest
-    request), so the programs compile once per cell.  Returns numpy arrays
-    of per-token gaps (``gap``; ``control_gap`` with ``control``, a name of
-    ``CONTROLS``) and the reference's top-two margins (``margin``)."""
-    m = Model.from_config(model)
+    request, rounded up to ``Q_BLOCK``), so the programs compile once per
+    cell.  Returns numpy arrays of per-token gaps (``gap``;
+    ``control_gap`` with ``control``, a name of ``CONTROLS``) and the
+    reference's top-two margins (``margin``)."""
+    m = family.Model.from_config(model)
     s_pad = _pad_to(seq_len, Q_BLOCK)
     b = n_rows
     tokens = np.zeros((b, s_pad), np.int32)
@@ -230,16 +139,16 @@ def gaps(model: dict, weight_seed: int, seqs, n_rows: int, seq_len: int,
     toks_a[:n_tok] = toks
 
     key = jax.random.PRNGKey(weight_seed)
-    embed, head = jax.jit(weights.embed_and_head, static_argnums=0)(m, key)
+    embed, head = jax.jit(family.embed_and_head, static_argnums=0)(m, key)
     x = embed[jnp.asarray(tokens)].astype(jnp.float32)
     del embed
     ctl = CONTROLS[control] if control else None
     xc = x if ctl else None
     for i in range(m.n_layers):
-        w = _quantize_layer(m, layer_weights(m, key, i))
-        x = layer(m, x, w)
+        w = family.layer_weights(m, key, i)
+        x = family.layer(m, x, w, REFERENCE)
         if ctl:
-            xc = layer(m, xc, w, ctl)
+            xc = family.layer(m, xc, w, ctl)
         del w
     flat = x.reshape(b * s_pad, -1)
     flat_c = None if xc is None else xc.reshape(b * s_pad, -1)

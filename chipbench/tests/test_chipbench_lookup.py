@@ -2,6 +2,7 @@
 only are found by name from BENCHMARK.json."""
 
 import json
+import re
 
 import pytest
 
@@ -14,6 +15,8 @@ def test_files_only_are_found_by_name(tmp_path):
     got = load_cell(cell, root=tmp_path, bench_dir=tmp_path / "chipbench")
     assert got.traffic["clients"] == 4
     assert got.config["engine"] == "slot"
+    assert got.family.__file__ == str(tmp_path / "chipbench" / "families"
+                                      / "dense.py")
     assert [m.name for m in got.per_layer] == ["new_metric"]
     ctx = type("Ctx", (), {"trace": {
         "module_runs": {"jit__decode_step": 10},
@@ -23,14 +26,36 @@ def test_files_only_are_found_by_name(tmp_path):
         "out_tok_s", "itl_p95_ms", "setup_s"}
 
 
-def test_missing_pieces_are_errors(tmp_path):
-    cell = make_bench(tmp_path, mix_name="gone", metric="absent")
-    (tmp_path / "chipbench" / "traffic" / "gone.json").unlink()
-    with pytest.raises(FileNotFoundError):
-        load_cell(cell, root=tmp_path, bench_dir=tmp_path / "chipbench")
-    with pytest.raises(KeyError):
-        load_cell("no.such.cell", root=tmp_path,
-                  bench_dir=tmp_path / "chipbench")
+def _remove(bench, piece):
+    """Take ``piece`` out of the tree ``make_bench`` wrote; returns the
+    cell to load, the error and a name the error's message holds."""
+    cell = "tiny-slot.gone"
+    if piece == "traffic":
+        (bench / "traffic" / "gone.json").unlink()
+        return cell, FileNotFoundError, "gone.json"
+    if piece == "workload":
+        return "no.such.cell", KeyError, "no.such.cell"
+    if piece == "family_key":
+        path = bench / "configs" / "tiny-slot.json"
+        conf = json.loads(path.read_text())
+        del conf["family"]
+        path.write_text(json.dumps(conf))
+        return cell, KeyError, "tiny-slot.json"
+    if piece == "family_module":
+        (bench / "families" / "dense.py").unlink()
+        return cell, FileNotFoundError, "dense.py"
+    (bench / "families" / "dense.py").write_text("Model = None\n")
+    return cell, AttributeError, "dense.py defines no params"
+
+
+@pytest.mark.parametrize("piece", ["traffic", "workload", "family_key",
+                                   "family_module", "family_api"])
+def test_missing_pieces_are_errors(tmp_path, piece):
+    make_bench(tmp_path, mix_name="gone", metric="absent")
+    bench = tmp_path / "chipbench"
+    cell, error, named = _remove(bench, piece)
+    with pytest.raises(error, match=re.escape(named)):
+        load_cell(cell, root=tmp_path, bench_dir=bench)
 
 
 def test_the_repo_benchmark_loads_every_cell():

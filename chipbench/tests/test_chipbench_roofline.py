@@ -1,8 +1,13 @@
-"""Peaks and the operation and byte counts the roofline shares read."""
+"""Peaks and the operation and byte counts the roofline shares read: the
+packed linear's in ``roofline.py``, the dense model's in its family
+module."""
 
 import pytest
 
 from chipbench import roofline
+from chipbench.bench import HERE, load_family
+
+dense = load_family(HERE, "dense")
 
 DANUBE = dict(n_layers=24, d_model=3840, n_heads=32, n_kv_heads=8,
               d_ff=10240, vocab_size=32000, sliding_window=4096)
@@ -37,9 +42,9 @@ def test_unknown_device_is_an_error():
 
 
 def test_layer_linears_follow_the_mlp_variant():
-    assert [n for n, _, _ in roofline.layer_linears(DANUBE)] == \
+    assert [n for n, _, _ in dense.layer_linears(DANUBE)] == \
         ["wq", "wk", "wv", "wo", "up", "gate", "down"]
-    shapes = roofline.layer_linears(STARCODER)
+    shapes = dense.layer_linears(STARCODER)
     assert [n for n, _, _ in shapes] == ["wq", "wk", "wv", "wo", "up", "down"]
     assert ("wk", 4608, 512) in shapes
 
@@ -49,23 +54,23 @@ def test_forward_min_time_sums_every_linear():
     one = dict(DANUBE, n_layers=1)
     want = sum(roofline.min_time_s(*roofline.int4_linear_cost(16, k, n),
                                    393e12, 819e9)
-               for _, k, n in roofline.layer_linears(DANUBE))
-    assert roofline.forward_min_time_s(one, 16, peak, False) == \
+               for _, k, n in dense.layer_linears(DANUBE))
+    assert dense.forward_min_time_s(one, 16, peak, False) == \
         pytest.approx(want)
     head = roofline.min_time_s(*roofline.int4_linear_cost(16, 3840, 32000),
                                393e12, 819e9)
-    assert roofline.forward_min_time_s(one, 16, peak, True) == \
+    assert dense.forward_min_time_s(one, 16, peak, True) == \
         pytest.approx(want + head)
 
 
 def test_prompt_flops_is_the_sum_over_its_positions():
     for model in (DANUBE, STARCODER, dict(DANUBE, sliding_window=8)):
-        assert roofline.prompt_flops(model, 40) == sum(
-            roofline.position_flops(model, p) for p in range(40))
+        assert dense.prompt_flops(model, 40) == sum(
+            dense.position_flops(model, p) for p in range(40))
 
 
 def test_matmul_params_of_starcoder2():
-    layers, head = roofline.matmul_params(STARCODER)
+    layers, head = dense.matmul_params(STARCODER)
     per_layer = 4608 * 4608 * 2 + 4608 * 512 * 2 + 2 * 4608 * 18432
     assert layers == 16 * per_layer
     assert head == 4608 * 49152
@@ -83,12 +88,12 @@ def test_model_flops_spreads_the_prompt_over_its_prefill():
             times=[10.0, 11.0, 12.0])
     # a slow first step (a compile), then three more before the first token
     steps = [7.0, 8.0, 9.0, 10.0, 11.0, 12.0]
-    head = roofline.lm_head_flops(model)
-    prompt = roofline.prompt_flops(model, 64)
-    got = model_flops(model, [r], steps, 8.5, 11.0)
-    want = prompt / 2 + 2 * head + roofline.position_flops(model, 64)
+    head = dense.lm_head_flops(model)
+    prompt = dense.prompt_flops(model, 64)
+    got = model_flops(dense, model, [r], steps, 8.5, 11.0)
+    want = prompt / 2 + 2 * head + dense.position_flops(model, 64)
     assert got == pytest.approx(want)
     # a window before the first step holds none of the prompt
-    assert model_flops(model, [r], steps, 0.0, 6.0) == 0
-    assert model_flops(model, [r], steps, 0.0, 7.0) == pytest.approx(
+    assert model_flops(dense, model, [r], steps, 0.0, 6.0) == 0
+    assert model_flops(dense, model, [r], steps, 0.0, 7.0) == pytest.approx(
         prompt / 4)

@@ -7,7 +7,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import trace
-from chipbench.bench import load_reader, HERE
+from chipbench.bench import HERE, load_family, load_reader
 
 DATA = Path(__file__).parent / "data"
 
@@ -144,16 +144,203 @@ def test_load_reads_host_spans_from_a_cpu_trace(tmp_path):
 def test_kernel_roofline_reader_on_the_toy_trace():
     from chipbench import roofline
 
+    dense = load_family(HERE, "dense")
     model = dict(n_layers=1, d_model=256, n_heads=2, n_kv_heads=1,
                  d_ff=512, vocab_size=1000)
     peak = roofline.peaks("TPU v5 lite")
     ctx = type("Ctx", (), {})()
-    ctx.trace, ctx.peaks = trace.reduce(TOY), peak
+    ctx.trace, ctx.peaks, ctx.family = trace.reduce(TOY), peak, dense
     ctx.model, ctx.serve = model, {"n_slots": 16, "prefill_chunk": 16}
-    need = (roofline.forward_min_time_s(model, 16, peak, True)
-            + roofline.forward_min_time_s(dict(model, n_layers=0), 16, peak,
-                                          True))
+    ctx.counters = {"prefill_rows": 0, "prefill_steps": 0}
+    need = (dense.forward_min_time_s(model, 16, peak, True)
+            + dense.forward_min_time_s(dict(model, n_layers=0), 16, peak,
+                                       True))
     got = load_reader(HERE, "packed_kernel_roofline")(ctx)
     assert got == pytest.approx(100 * need / 200e-9)
+    # a prefill program's rows are the scheduler's over its prefill steps
+    ctx.trace = dict(ctx.trace, module_runs={"jit__prefill_chunk": 3})
+    ctx.counters = {"prefill_rows": 5 * 256, "prefill_steps": 5}
+    got = load_reader(HERE, "packed_kernel_roofline")(ctx)
+    assert got == pytest.approx(
+        100 * 3 * dense.forward_min_time_s(model, 256, peak, False) / 200e-9)
+    ctx.counters = {"prefill_rows": 0, "prefill_steps": 0}
+    assert load_reader(HERE, "packed_kernel_roofline")(ctx) is None
     ctx.trace = dict(ctx.trace, ops_s={"%fusion.1 = f()": 1.0})
     assert load_reader(HERE, "packed_kernel_roofline")(ctx) is None
+
+
+# One decode program's optimized HLO, cut to the instructions TOY_SCOPED
+# runs: a fusion holds the exponent of the scores (its fused body carries
+# the same metadata), the gather of the KV window, a kernel in ``mlp``, a
+# copy without metadata.
+TOY_HLO = """HloModule jit__decode_step, is_scheduled=true, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+%fused_computation.1 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  ROOT %exponential.4 = f32[8]{0} exponential(%param_0.1), metadata={op_name="jit(_decode_step)/while/body/closed_call/checkpoint/paged_attention/scores/exp" stack_frame_id=3}
+}
+
+ENTRY %main.9 (p.1: f32[8]) -> f32[8] {
+  %p.1 = f32[8]{0} parameter(0), metadata={op_name="cache[\\'attn\\'][\\'pages_k\\']"}
+  %while.1 = (s32[], f32[8]{0}) while(%tuple.1), condition=%cond.1, body=%body.1, metadata={op_name="jit(_decode_step)/while" stack_frame_id=1}
+  %fusion.1 = f32[8]{0} fusion(%p.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(_decode_step)/while/body/closed_call/checkpoint/paged_attention/scores/exp" stack_frame_id=3}
+  %gather.2 = f32[8]{0} gather(%p.1, %i.1), offset_dims={}, metadata={op_name="jit(_decode_step)/while/body/closed_call/checkpoint/paged_attention/kv_gather/gather" stack_frame_id=4}
+  %int4_matmul.7 = s32[16,256]{1,0} custom-call(%a.1, %b.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(_decode_step)/while/body/closed_call/checkpoint/mlp/jit(int4_matmul)/pallas_call" stack_frame_id=5}
+  ROOT %copy.3 = f32[8]{0} copy(%p.1)
+}
+"""
+
+TOY_SCOPED = dict(TOY, devices={"/device:TPU:0": [
+    ("%while.1 = (s32[], f32[8]) while(...)", 100, 460),
+    ("%fusion.1 = f32[8] fusion(...)", 100, 200),
+    ("%gather.2 = f32[8] gather(...)", 200, 250),
+    ("%int4_matmul.7 = s32[16,256] custom-call(...), "
+     'custom_call_target="tpu_custom_call"', 250, 300),
+    ("%mystery.9 = f32[8] fusion(...)", 300, 320),   # not in the text
+    ("%copy.3 = f32[8] copy(...)", 400, 450),
+    ("%int4_matmul.9 = s32[128,256] custom-call(...)", 900, 1000),
+]})
+
+
+@pytest.mark.parametrize("op_name, path", [
+    ("jit(_decode_step)/while/body/closed_call/checkpoint/paged_attention/"
+     "kv_gather/gather", "paged_attention/kv_gather"),
+    ("jit(_decode_step)/lm_head/jit(int4_prepacked_matmul_f32)/mul",
+     "lm_head"),
+    ("jit(_prefill_chunk)/...d,...d->.../dot_general", ""),
+    ("jit(_decode_step)/sample/vmap()/jit(clip)/min", "sample"),
+    ("jit(f)/cond/branch_1_fun/moe/router/top_k", "moe/router"),
+    ("cache['attn']['pages_k']", ""),
+    ("", ""),
+])
+def test_scope_path(op_name, path):
+    assert trace.scope_path(op_name) == path
+
+
+def test_op_names_of_an_hlo_text():
+    names = trace.op_names(TOY_HLO)
+    assert names["fusion.1"] == names["exponential.4"] == (
+        "jit(_decode_step)/while/body/closed_call/checkpoint/"
+        "paged_attention/scores/exp")
+    assert names["copy.3"] == ""
+    assert names["p.1"] == "cache[\\'attn\\'][\\'pages_k\\']"
+    assert "main.9" not in names and "fused_computation.1" not in names
+    assert trace.instruction("%int4_matmul.7 = s32[16,256] custom-call(x)") \
+        == "int4_matmul.7"
+
+
+def test_scope_join_on_the_toy_trace():
+    """Self time of each run's operations by scope path, joined through
+    the program's text; a program with no text joins nothing."""
+    red = trace.reduce(TOY_SCOPED, hlo={"jit__decode_step": TOY_HLO})
+    scopes = red["scopes_s"]["jit__decode_step"]
+    assert scopes == {
+        "paged_attention/scores": pytest.approx(100e-9),
+        "paged_attention/kv_gather": pytest.approx(50e-9),
+        "mlp": pytest.approx(50e-9),
+        # the loop's own 90 ns and the copy's 50
+        "": pytest.approx(140e-9)}
+    assert red["scope_matched"] == {
+        "jit__decode_step": pytest.approx(340 / 360),
+        "jit__lm_head": 0.0}
+    assert red["scopes_s"]["jit__lm_head"] == {}
+    # without texts nothing is joined, and the rest of the reduction stays
+    bare = trace.reduce(TOY_SCOPED)
+    assert bare["scope_matched"] == {"jit__decode_step": 0.0,
+                                     "jit__lm_head": 0.0}
+    assert {k: v for k, v in bare.items()
+            if k not in ("scopes_s", "scope_matched")} == \
+        {k: v for k, v in red.items() if k not in ("scopes_s",
+                                                    "scope_matched")}
+
+
+def test_decode_attention_reader_on_the_toy_trace():
+    ctx = type("Ctx", (), {})()
+    read = load_reader(HERE, "decode_attention_ms")
+    ctx.trace = trace.reduce(TOY_SCOPED, hlo={"jit__decode_step": TOY_HLO})
+    assert read(ctx) == pytest.approx(150e-6)     # ms per decode run
+    ctx.trace = trace.reduce(TOY_SCOPED)
+    assert read(ctx) is None
+    ctx.trace = None
+    assert read(ctx) is None
+
+
+def test_program_texts_are_taken_after_warm_up():
+    """The recorder notes each jitted program's shapes while the engine
+    warms up, hands the engine its own programs back, and afterwards
+    yields each program's optimized HLO with the model's scopes in its
+    metadata, without compiling anything again."""
+    import dataclasses
+
+    import jax
+
+    from chipbench.harness import Programs
+    from chipbench.tests.tiny import config
+    from repro.models.registry import get_config
+    from repro.serving import ContinuousEngine, ServeConfig
+
+    dense = load_family(HERE, "dense")
+    conf = config("continuous")
+    cfg = dataclasses.replace(get_config(conf["arch"]), **conf["model"])
+    params = dense.params(dense.Model.from_config(conf["model"]),
+                          jax.random.PRNGKey(3))
+    engine = ContinuousEngine(cfg, params, ServeConfig(**conf["serve"],
+                                                       seed=3))
+    sample = engine._sample
+    programs = Programs(engine)
+    for n in (7, 3):
+        engine.submit(list(range(2, 2 + n)), max_new=3, admit=False)
+    for _ in range(8):
+        engine.step()
+    programs.stop()
+    assert engine._sample is sample
+    assert "_decode_step" not in vars(engine)
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, dur, **kw: compiles.append(event)
+        if "backend_compile" in event else None)
+    texts = programs.texts()
+    assert not compiles
+    assert {"jit__decode_step", "jit__prefill_chunk", "jit__lm_head"} <= \
+        set(texts)
+    paths = {trace.scope_path(n)
+             for n in trace.op_names(texts["jit__decode_step"]).values()}
+    assert {"paged_attention/kv_gather", "paged_attention/scores", "mlp",
+            "lm_head", "sample"} <= paths
+
+
+def test_recorded_decode_program_scopes():
+    """The decode program of the tiny continuous engine as the TPU
+    compiler writes it (compiled for a described v5e, no chip; op times
+    made up, see the file's ``source``): every operation is found in the
+    program's text, and the paged-attention reader sums the operations
+    whose ``op_name`` holds the scope, against a plain search of the
+    text."""
+    ex = json.loads((DATA / "decode_scopes_excerpt.json").read_text())
+    text = ex["hlo"]["jit__decode_step"]
+    red = trace.reduce(ex, hlo=ex["hlo"])
+    assert red["scope_matched"] == {"jit__decode_step": 1.0}
+    scopes = red["scopes_s"]["jit__decode_step"]
+    assert {"paged_attention/kv_write", "paged_attention/kv_gather",
+            "paged_attention/scores", "mlp", "lm_head", "sample"} <= \
+        set(scopes)
+    ops = ex["devices"]["/device:TPU:0"]
+    want = 0
+    for name, s, e in ops:
+        head = name.split(" = ", 1)[0]
+        line = next(ln for ln in text.splitlines()
+                    if ln.lstrip().removeprefix("ROOT ").startswith(
+                        head + " = "))
+        if "/paged_attention/" in line.split("op_name=", 1)[-1]:
+            want += e - s
+    assert want > 0
+    ctx = type("Ctx", (), {})()
+    ctx.trace = red
+    got = load_reader(HERE, "decode_attention_ms")(ctx)
+    assert got == pytest.approx(want * 1e-6)   # one run, ns -> ms
+    # self times add up to the time some operation ran
+    busy, end = 0, 0
+    for _, s, e in sorted(ops, key=lambda o: o[1]):
+        busy += max(0, e - max(s, end))
+        end = max(end, e)
+    assert sum(scopes.values()) == pytest.approx(busy * 1e-9)

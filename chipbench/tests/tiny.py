@@ -1,6 +1,7 @@
 """A benchmark tree at a size a CPU test can run: one tiny configuration
-of each engine, one tiny closed-loop mix, written as files only; and
-``on_cpu``, which lets a test drive a run on the CPU."""
+of each engine, one tiny closed-loop mix, the dense family module,
+written as files only; and ``on_cpu``, which lets a test drive a run on
+the CPU."""
 
 from __future__ import annotations
 
@@ -26,7 +27,7 @@ def config(engine: str = "slot") -> dict:
         "name": f"tiny-{engine}",
         "source": "a CPU-sized cut of the registry's models",
         "arch": "h2o-danube-3-4b" if engine == "slot" else "starcoder2-7b",
-        "model": model, "engine": engine,
+        "family": "dense", "model": model, "engine": engine,
         "serve": {"n_slots": 4, "max_len": 64, "prefill_chunk": 4,
                   "quant_mode": "int4_packed", "eos_token": 256},
         "check": {"logit_gap": TINY_LIMIT},
@@ -45,10 +46,11 @@ MIX = {
 def make_bench(root: Path, engine: str = "slot", mix_name: str = "tinymix",
                metric: str | None = None) -> str:
     """Write ``root/BENCHMARK.json`` and ``root/chipbench/{configs,traffic,
-    metrics}`` with one cell; returns the cell's name."""
+    metrics,families}`` with one cell; returns the cell's name."""
     bench = root / "chipbench"
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "families"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
+    shutil.copy(HERE / "families" / "dense.py", bench / "families")
     conf = config(engine)
     (bench / "configs" / f"{conf['name']}.json").write_text(json.dumps(conf))
     (bench / "traffic" / f"{mix_name}.json").write_text(json.dumps(MIX))
